@@ -234,19 +234,45 @@ def model_performance(gemms: Iterable[GemmShape], cfg: MxuConfig) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# TPU-side roofline constants (brief-specified v5e-class targets)
+# TPU-side roofline: published per-chip peaks keyed by jax's device_kind
 # ---------------------------------------------------------------------------
 
-TPU_PEAK_FLOPS_BF16 = 197e12      # per chip
-TPU_HBM_BW = 819e9                # bytes/s per chip
-TPU_ICI_BW = 50e9                 # bytes/s per link
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    bf16_flops: float     # FLOP/s
+    int8_ops: float       # OP/s
+    hbm_bw: float         # bytes/s
+    ici_bw: float         # bytes/s, all chip-to-chip links of one chip
+
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect.
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(bf16_flops=197e12, int8_ops=393e12,
+                             hbm_bw=819e9, ici_bw=1600e9 / 8),
+}
+# The production mesh the dry-run lowers for is a 16x16 v5e slice.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of one chip of ``device_kind`` (``jax.Device.device_kind``).
+    A device the table does not know is an error, never a default."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(CHIP_PEAKS)}"
+                         ) from None
 
 
 def tpu_roofline_terms(hlo_flops: float, hlo_bytes: float,
-                       collective_bytes: float, chips: int) -> dict:
-    compute = hlo_flops / (chips * TPU_PEAK_FLOPS_BF16)
-    memory = hlo_bytes / (chips * TPU_HBM_BW)
-    collective = collective_bytes / (chips * TPU_ICI_BW)
+                       collective_bytes: float, chips: int,
+                       device_kind: str = DRYRUN_DEVICE_KIND) -> dict:
+    peaks = chip_peaks(device_kind)
+    compute = hlo_flops / (chips * peaks.bf16_flops)
+    memory = hlo_bytes / (chips * peaks.hbm_bw)
+    collective = collective_bytes / (chips * peaks.ici_bw)
     terms = dict(compute_s=compute, memory_s=memory, collective_s=collective)
     terms["bottleneck"] = max(terms, key=lambda k: terms[k] if k.endswith("_s") else -1)
     return terms

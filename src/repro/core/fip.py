@@ -56,6 +56,18 @@ def baseline_matmul(a: Array, b: Array, *, precision=jax.lax.Precision.HIGHEST) 
     return jnp.matmul(a.astype(acc), b.astype(acc), precision=precision)
 
 
+def _pairs(x: Array, axis: int):
+    """(odd, even) pair halves of ``x`` along ``axis`` (0-based positions 0,
+    2, 4, ... and 1, 3, 5, ...) via a reshape to a pair axis of size 2: the
+    TPU compiler turns stride-2 slices into gathers, this into plain
+    slices."""
+    axis %= x.ndim
+    shp = x.shape
+    x = x.reshape(*shp[:axis], shp[axis] // 2, 2, *shp[axis + 1:])
+    return (jax.lax.index_in_dim(x, 0, axis + 1, keepdims=False),
+            jax.lax.index_in_dim(x, 1, axis + 1, keepdims=False))
+
+
 # ---------------------------------------------------------------------------
 # Eqs. (3) / (4): correction terms
 # ---------------------------------------------------------------------------
@@ -64,16 +76,16 @@ def fip_alpha(a: Array) -> Array:
     """Eq. (3): alpha_i = sum_j a_{i,2j-1} * a_{i,2j}.  a: (..., M, K) -> (..., M)."""
     _check_even_k(a.shape[-1])
     acc = _acc_dtype(a.dtype)
-    a = a.astype(acc)
-    return jnp.sum(a[..., 0::2] * a[..., 1::2], axis=-1)
+    a_odd, a_evn = _pairs(a.astype(acc), -1)
+    return jnp.sum(a_odd * a_evn, axis=-1)
 
 
 def fip_beta(b: Array) -> Array:
     """Eq. (4): beta_j = sum_i b_{2i-1,j} * b_{2i,j}.  b: (K, N) -> (N,)."""
     _check_even_k(b.shape[0])
     acc = _acc_dtype(b.dtype)
-    b = b.astype(acc)
-    return jnp.sum(b[0::2, :] * b[1::2, :], axis=0)
+    b_odd, b_evn = _pairs(b.astype(acc), 0)
+    return jnp.sum(b_odd * b_evn, axis=0)
 
 
 # ---------------------------------------------------------------------------
@@ -92,8 +104,8 @@ def fip_cross_term(a: Array, b: Array, *, k_chunk: int = 0) -> Array:
     acc = _acc_dtype(jnp.result_type(a.dtype, b.dtype))
     a = a.astype(acc)
     b = b.astype(acc)
-    a_odd, a_evn = a[..., 0::2], a[..., 1::2]          # a_{i,2k-1}, a_{i,2k}
-    b_odd, b_evn = b[0::2, :], b[1::2, :]              # b_{2k-1,j}, b_{2k,j}
+    a_odd, a_evn = _pairs(a, -1)                       # a_{i,2k-1}, a_{i,2k}
+    b_odd, b_evn = _pairs(b, 0)                        # b_{2k-1,j}, b_{2k,j}
 
     def chunk_sum(ao, ae, bo, be):
         t1 = ao[..., :, :, None] + be[None, :, :]      # a_{i,2k-1} + b_{2k,j}

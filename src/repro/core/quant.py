@@ -16,6 +16,7 @@ baseline GEMM, validated in tests/test_quant.py.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -164,7 +165,25 @@ def prepare_quantized_dense(w: Array, *, dtype=jnp.int8,
         adjuster at decode time.
     """
     counters["prepare_dense"] += 1
-    qmin, qmax = _INT_INFO[jnp.dtype(dtype)]
+    return _prepare_quantized_dense(w, dtype=jnp.dtype(dtype),
+                                    symmetric=symmetric)
+
+
+# One compiled program per weight shape that maps over the stacked layers:
+# run op by op over a whole stacked (L, K, N) weight, the f32 copies and pair
+# slices need several times its size in device memory at once.
+@functools.partial(jax.jit, static_argnames=("dtype", "symmetric"))
+def _prepare_quantized_dense(w: Array, *, dtype, symmetric: bool) -> dict:
+    one = functools.partial(_quantize_2d, dtype=dtype, symmetric=symmetric)
+    if w.ndim == 2:
+        return one(w)
+    lead = w.shape[:-2]
+    out = jax.lax.map(one, w.reshape(-1, *w.shape[-2:]))
+    return jax.tree.map(lambda x: x.reshape(*lead, *x.shape[1:]), out)
+
+
+def _quantize_2d(w: Array, *, dtype, symmetric: bool) -> dict:
+    qmin, qmax = _INT_INFO[dtype]
     w = w.astype(jnp.float32)
     if symmetric:
         amax = jnp.max(jnp.abs(w), axis=-2)

@@ -11,7 +11,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.compat import resolve_interpret, tpu_compiler_params
 
@@ -37,13 +36,10 @@ def pad_to_blocks(a: Array, b: Array, bm: int, bn: int, bk: int):
 
 def _kernel(a_ref, b_ref, o_ref, *, acc_dtype):
     k = pl.program_id(2)
-    a = a_ref[...].astype(acc_dtype)
-    b = b_ref[...].astype(acc_dtype)
-    if jnp.issubdtype(acc_dtype, jnp.integer):
-        part = jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
-                                   preferred_element_type=acc_dtype)
-    else:
-        part = jnp.dot(a, b, preferred_element_type=acc_dtype)
+    # Operands go to the MXU in their own dtype (int8 x int8 -> int32, bf16 x
+    # bf16 -> f32); Mosaic refuses an int32 x int32 dot.
+    part = jax.lax.dot_general(a_ref[...], b_ref[...], (((1,), (0,)), ((), ())),
+                               preferred_element_type=acc_dtype)
 
     @pl.when(k == 0)
     def _init():
@@ -66,8 +62,10 @@ def baseline_gemm(a: Array, b: Array, *, bm: int = 128, bn: int = 128,
     interpret = resolve_interpret(interpret)
     m0, k0 = a.shape
     k2, n0 = b.shape
-    assert k0 == k2
-    a, b = pad_to_blocks(a, b, bm, bn, bk)
+    if k0 != k2:
+        raise ValueError(f"contraction mismatch {k0} vs {k2}")
+    dtype = jnp.result_type(a.dtype, b.dtype)
+    a, b = pad_to_blocks(a.astype(dtype), b.astype(dtype), bm, bn, bk)
     m, k = a.shape
     n = b.shape[1]
     acc_dtype = (jnp.int32 if jnp.issubdtype(a.dtype, jnp.integer)

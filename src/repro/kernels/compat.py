@@ -1,18 +1,18 @@
-"""Compat layer over Pallas TPU API drift + backend probes.
+"""Pallas TPU compiler params + backend probes.
 
-`pltpu.TPUCompilerParams` was renamed to `pltpu.CompilerParams` across JAX
-releases; the installed toolchain may carry either name. Every kernel builds
-its compiler params through :func:`tpu_compiler_params` so one probe point
-absorbs the drift (tests/test_kernels.py exercises all kernels in interpret
-mode at collection-adjacent cost precisely so this breaks loudly, not deep in
-a smoke test).
+Every kernel builds its compiler params through :func:`tpu_compiler_params`
+(``pltpu.CompilerParams``), one place to change if the Pallas API moves
+again; tests/test_kernels.py builds and runs every kernel entry point in
+interpret mode so such a move breaks loudly.
 
 This module is also the single place kernels ask "should Pallas run compiled
 or interpreted?": every kernel entry point takes ``interpret=None`` meaning
 "auto" and resolves it through :func:`resolve_interpret` — compiled on a TPU
 backend, interpret-mode emulation everywhere else (the CPU CI container). An
 explicit ``True``/``False`` always wins, so tests can force interpret mode on
-any backend and a TPU user can force interpretation for debugging.
+any backend and a TPU user can force interpretation for debugging. A kernel
+that Mosaic cannot compile raises on TPU; nothing falls back to interpret
+mode there.
 """
 from __future__ import annotations
 
@@ -22,21 +22,11 @@ from typing import Callable, Optional
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
-_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams", None)
 
-
-def tpu_compiler_params(**kwargs):
-    """Build a Pallas TPU compiler-params object under either JAX spelling.
-
-    kwargs are passed through (e.g. dimension_semantics=("parallel", ...)).
-    Returns None when the installed Pallas exposes neither class, in which
-    case pallas_call simply runs without TPU compiler hints — correct, if
-    slower, which is the right degradation for interpret-mode CPU CI.
-    """
-    if _PARAMS_CLS is None:
-        return None
-    return _PARAMS_CLS(**kwargs)
+def tpu_compiler_params(**kwargs) -> pltpu.CompilerParams:
+    """Pallas TPU compiler params; kwargs pass through (e.g.
+    ``dimension_semantics=("parallel", ...)``)."""
+    return pltpu.CompilerParams(**kwargs)
 
 
 def is_tpu_backend() -> bool:

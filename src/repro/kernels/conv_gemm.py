@@ -14,7 +14,7 @@ GEMM. These kernels close that gap: the Algorithm-1 address arithmetic runs
 
 — so the A matrix only ever exists as (bm, bk) VMEM tiles; HBM holds the
 spatially-padded input exactly once. The arithmetic bodies mirror the GEMM
-kernels (baseline dot / FIP pair algebra / FFIP y-delta carry) operation for
+kernels (baseline dot / FIP pair algebra / FFIP y-delta prefix) operation for
 operation, so for a fixed (bn, bk) a fused conv is BIT-IDENTICAL to running
 the same Pallas GEMM over the materialized A — the reference oracle tests
 rely on this.
@@ -41,14 +41,13 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import fip
 from repro.kernels import compat
 from repro.core.im2col import as_pair, conv_out_hw, Size2
 from repro.kernels.compat import resolve_interpret, tpu_compiler_params
-from repro.kernels.ffip_gemm import ffip_tile
-from repro.kernels.fip_gemm import fip_tile
+from repro.kernels.ffip_gemm import block_deltas, ffip_tile
+from repro.kernels.fip_gemm import deinterleave, fip_tile, pad_to
 from repro.kernels import ops as kops
 from repro.obs import profile as _obs_profile
 
@@ -119,44 +118,34 @@ def _gather_tile(x_ref, g, mi, ki, *, bm: int, bk: int, geom: ConvGeom):
     return jnp.where(k_idx < geom.k, a, jnp.zeros_like(a))
 
 
-def _conv_kernel_mac(x_ref, b_ref, o_ref, *, acc_dtype, algo: str,
-                     fold_beta: bool, bm: int, bk: int, geom: ConvGeom):
-    """Baseline / FIP bodies; grid (B, G, M/bm, N/bn, K/bk), K innermost.
-    Mirrors baseline_gemm/fip_gemm exactly, with A gathered, not loaded."""
+def _pair_halves(a):
+    """Deinterleave a gathered (bm, bk) tile into the odd/even pair halves
+    the FIP tiles take. These are strided lane slices, which Mosaic refuses,
+    as it refuses the in-kernel gather above: until the gather source is
+    tiled, the fused conv kernels run in interpret mode and raise when
+    compiled for TPU."""
+    return a[:, 0::2], a[:, 1::2]
+
+
+def _conv_kernel(x_ref, *refs, acc_dtype, algo: str, fold_beta: bool,
+                 bm: int, bk: int, geom: ConvGeom):
+    """Grid (B, G, M/bm, N/bn, K/bk), K innermost. Mirrors baseline_gemm /
+    fip_gemm / ffip_gemm exactly, with A gathered, not loaded. refs: the B
+    block (baseline), its odd/even row halves (FIP) or those of the
+    block-encoded y (FFIP), then the output block."""
+    *b_refs, o_ref = refs
     g = pl.program_id(1)
     mi = pl.program_id(2)
     ki = pl.program_id(4)
-    a = _gather_tile(x_ref, g, mi, ki, bm=bm, bk=bk, geom=geom).astype(acc_dtype)
-    b = b_ref[0].astype(acc_dtype)               # (bk, bn)
+    a = _gather_tile(x_ref, g, mi, ki, bm=bm, bk=bk, geom=geom)
     if algo == "baseline":
-        if jnp.issubdtype(acc_dtype, jnp.integer):
-            part = jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
-                                       preferred_element_type=acc_dtype)
-        else:
-            part = jnp.dot(a, b, preferred_element_type=acc_dtype)
-    else:
-        part = fip_tile(a, b, fold_beta=fold_beta)   # shared Eq. (2) algebra
-
-    @pl.when(ki == 0)
-    def _init():
-        o_ref[...] = part[None, None]
-
-    @pl.when(ki != 0)
-    def _acc():
-        o_ref[...] += part[None, None]
-
-
-def _conv_kernel_ffip(x_ref, y_ref, o_ref, carry_ref, *, acc_dtype,
-                      fold_beta: bool, bm: int, bk: int, geom: ConvGeom):
-    """FFIP body; grid (B, G, M/bm, K/bk, N/bn), N innermost so the carry
-    sweeps output columns for a fixed (m, k) stripe — mirrors ffip_gemm."""
-    g = pl.program_id(1)
-    mi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nn = pl.program_id(4)
-    a = _gather_tile(x_ref, g, mi, ki, bm=bm, bk=bk, geom=geom).astype(acc_dtype)
-    y = y_ref[0].astype(acc_dtype)               # (bk, bn) weight deltas
-    part = ffip_tile(a, y, carry_ref, nn, fold_beta=fold_beta)  # shared
+        part = jax.lax.dot_general(a, b_refs[0][0], (((1,), (0,)), ((), ())),
+                                   preferred_element_type=acc_dtype)
+    else:                                        # shared Eq. (2) / (7) algebra
+        tile = fip_tile if algo == "fip" else ffip_tile
+        part = tile(*_pair_halves(a.astype(acc_dtype)),
+                    b_refs[0][0].astype(acc_dtype),
+                    b_refs[1][0].astype(acc_dtype), fold_beta=fold_beta)
 
     @pl.when(ki == 0)
     def _init():
@@ -180,43 +169,30 @@ def _fused_flat(xf: Array, bg: Array, *, geom: ConvGeom, algo: str, bm: int,
     mp = -(-geom.m // bm) * bm
     kp = -(-ks // bk) * bk
     np_ = -(-ng // bn) * bn
-    if (kp, np_) != (ks, ng):
-        bg = jnp.pad(bg, ((0, 0), (0, kp - ks), (0, np_ - ng)))
-    x_spec = pl.BlockSpec((1, length), lambda bi, g, i, p3, p4: (bi, 0))
-    if algo == "ffip":
-        grid = (n_b, n_g, mp // bm, kp // bk, np_ // bn)   # N innermost
-        kernel = functools.partial(_conv_kernel_ffip, acc_dtype=acc_dtype,
-                                   fold_beta=fold_beta, bm=bm, bk=bk,
-                                   geom=geom)
-        in_specs = [x_spec,
-                    pl.BlockSpec((1, bk, bn), lambda bi, g, i, kk, j: (g, kk, j))]
-        out_spec = pl.BlockSpec((1, 1, bm, bn),
-                                lambda bi, g, i, kk, j: (bi, g, i, j))
-        scratch = [pltpu.VMEM((bk, 1), acc_dtype)]
-        semantics = ("parallel", "parallel", "parallel", "arbitrary",
-                     "arbitrary")
-    else:
-        grid = (n_b, n_g, mp // bm, np_ // bn, kp // bk)   # K innermost
-        kernel = functools.partial(_conv_kernel_mac, acc_dtype=acc_dtype,
-                                   algo=algo, fold_beta=fold_beta, bm=bm,
-                                   bk=bk, geom=geom)
-        in_specs = [x_spec,
-                    pl.BlockSpec((1, bk, bn), lambda bi, g, i, j, kk: (g, kk, j))]
-        out_spec = pl.BlockSpec((1, 1, bm, bn),
-                                lambda bi, g, i, j, kk: (bi, g, i, j))
-        scratch = []
-        semantics = ("parallel", "parallel", "parallel", "parallel",
-                     "arbitrary")
+    if algo == "baseline":
+        dtype = jnp.result_type(xf.dtype, bg.dtype)
+        xf = xf.astype(dtype)
+        b_ops = [pad_to(bg.astype(dtype), (n_g, kp, np_))]
+        b_block = (1, bk, bn)
+    else:                                # FIP family: deinterleaved halves
+        if algo == "ffip":
+            bg = block_deltas(pad_to(bg, (n_g, ks, np_)), bn)
+        b_ops = [pad_to(h, (n_g, kp // 2, np_)) for h in deinterleave(bg, 1)]
+        b_block = (1, bk // 2, bn)
     return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
+        functools.partial(_conv_kernel, acc_dtype=acc_dtype, algo=algo,
+                          fold_beta=fold_beta, bm=bm, bk=bk, geom=geom),
+        grid=(n_b, n_g, mp // bm, np_ // bn, kp // bk),
+        in_specs=[pl.BlockSpec((1, length), lambda bi, g, i, j, kk: (bi, 0))]
+        + [pl.BlockSpec(b_block, lambda bi, g, i, j, kk: (g, kk, j))]
+        * len(b_ops),
+        out_specs=pl.BlockSpec((1, 1, bm, bn),
+                               lambda bi, g, i, j, kk: (bi, g, i, j)),
         out_shape=jax.ShapeDtypeStruct((n_b, n_g, mp, np_), acc_dtype),
-        scratch_shapes=scratch,
-        compiler_params=tpu_compiler_params(dimension_semantics=semantics),
+        compiler_params=tpu_compiler_params(dimension_semantics=(
+            "parallel", "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(xf, bg)
+    )(xf, *b_ops)
 
 
 # Offline weight derivations (stack / evenize / y-deltas) memoize through the

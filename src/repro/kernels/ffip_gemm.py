@@ -5,14 +5,18 @@ y (Eq. 9) rather than B, and reconstructs the g-term offsets by accumulating
 y along the output-column direction — exactly what the FFIP PE chain does,
 where each g register adds one y as the value hops to the next column's PE.
 
-Mapping to a blocked kernel: grid is (M/bm, K/bk, N/bn) with the N axis
-innermost. A VMEM scratch ``carry`` holds the running column prefix of y for
-the current (m, k) stripe; within a block the prefix is a cumsum. Thus
-B is never materialised in HBM — only y travels (the paper's §4.4 notes y can
-be precomputed and stored at 1 extra bit).
+Mapping to a blocked kernel: each ``bn``-column block of the output is its
+own FFIP array whose first column is fed b itself (as the paper's array is
+fed y_{i,1} = b_{i,1}): :func:`block_deltas` re-encodes the Eq. 9 deltas so
+every block starts from b, and the kernel rebuilds the block's B tile with a
+log-step roll-and-add prefix sum along the columns. Blocks need no state from
+one another, so the grid is (M/bm, N/bn, K/bk) with K innermost and the
+output block accumulates in place, as in fip_gemm. B is never materialised in
+HBM — only y travels (the paper's §4.4 notes y can be precomputed and stored
+at 1 extra bit). Operands use fip_gemm's deinterleaved pair layout.
 
 The α row is computed in-kernel (the paper's extra MAC row, Fig. 3); β is
-reconstructed from the carried prefix (or pre-folded into bias, Eq. 15).
+reconstructed from the rebuilt tile (or pre-folded into bias, Eq. 15).
 """
 from __future__ import annotations
 
@@ -24,8 +28,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import compat
-from repro.kernels.baseline_gemm import pad_to_blocks
 from repro.kernels.compat import resolve_interpret, tpu_compiler_params
+from repro.kernels.fip_gemm import cross_term, deinterleave, pad_to
 
 from repro.core import fip
 
@@ -41,42 +45,56 @@ def _y_for(b: Array) -> Array:
     return compat.derived.get(Y_TAG, b, fip.make_y)
 
 
-def ffip_tile(a, y, carry_ref, nn, *, fold_beta: bool):
-    """Eqs. (7)-(9) on one tile: reconstruct the weight offsets from the y
-    deltas via the column prefix carried in ``carry_ref`` (reset when the N
-    sweep restarts at ``nn == 0``), then the pair product-sum minus alpha
-    (and beta unless folded). SHARED between this GEMM kernel and the fused
+def lane_prefix_sum(x: Array) -> Array:
+    """Inclusive prefix sum along the lane (last) axis of a 2-D tile in
+    log2(width) roll-and-add steps (Hillis-Steele): Mosaic lowers
+    ``pltpu.roll`` but not ``cumsum``. Exact for integers."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    shift = 1
+    while shift < x.shape[1]:
+        x = x + jnp.where(lane >= shift, pltpu.roll(x, shift, 1),
+                          jnp.zeros_like(x))
+        shift *= 2
+    return x
+
+
+def block_deltas(y: Array, bn: int) -> Array:
+    """Re-encode Eq. 9 deltas ``y`` (..., K, N), N a multiple of ``bn``, so
+    the first column of every ``bn``-column block holds b itself: add to it
+    the sum of all deltas before the block. Exact for integers."""
+    *lead, k, n = y.shape
+    y3 = y.reshape(*lead, k, n // bn, bn)
+    sums = jnp.sum(y3, axis=-1)
+    before = jnp.cumsum(sums, axis=-1) - sums       # b just before each block
+    return y3.at[..., 0].add(before).reshape(y.shape)
+
+
+def ffip_tile(a_odd, a_evn, y_odd, y_evn, *, fold_beta: bool):
+    """Eqs. (7)-(9) on one tile of deinterleaved operands whose y block
+    starts from b (:func:`block_deltas`): rebuild the weight tile by the
+    column prefix sum, then the pair product-sum minus alpha (and beta
+    unless folded). SHARED between this GEMM kernel and the fused
     implicit-im2col conv kernels (kernels/conv_gemm.py) — one algebra, two
     A-tile sources, so fused conv == materialized GEMM bit-for-bit."""
-
-    @pl.when(nn == 0)
-    def _reset():
-        carry_ref[...] = jnp.zeros_like(carry_ref)
-
     # Free-pipeline reconstruction: b_{k,j} = b_{k,j-1} + y_{k,j} (Eq. 8c/9).
-    b = carry_ref[...] + jnp.cumsum(y, axis=1)  # (bk, bn)
-    carry_ref[...] = b[:, -1:]                  # prefix for the next N block
+    b_odd = lane_prefix_sum(y_odd)                      # (bk/2, bn)
+    b_evn = lane_prefix_sum(y_evn)
 
-    # g terms (Eqs. 8a/8b): pair-swapped A plus the reconstructed offsets.
-    a_odd, a_evn = a[:, 0::2], a[:, 1::2]
-    b_odd, b_evn = b[0::2, :], b[1::2, :]
-    g1 = a_evn[:, :, None] + b_odd[None, :, :]  # g_{i,2k-1}
-    g2 = a_odd[:, :, None] + b_evn[None, :, :]  # g_{i,2k}
-    cross = jnp.sum(g1 * g2, axis=1)            # Eq. (7) product-sum
-    alpha = jnp.sum(a_odd * a_evn, axis=1)      # alpha MAC row (Fig. 3)
-    part = cross - alpha[:, None]
+    # g terms (Eqs. 8a/8b): pair-swapped A plus the reconstructed offsets,
+    # g_{i,2k-1} = a_{i,2k} + b_{2k-1,j} and g_{i,2k} = a_{i,2k-1} + b_{2k,j}.
+    part = cross_term(a_evn, a_odd, b_odd, b_evn)       # Eq. (7) product-sum
+    part = part - jnp.sum(a_odd * a_evn, axis=1, keepdims=True)  # alpha row
     if not fold_beta:
-        beta = jnp.sum(b_odd * b_evn, axis=0)
-        part = part - beta[None, :]
+        part = part - jnp.sum(b_odd * b_evn, axis=0, keepdims=True)
     return part
 
 
-def _kernel(a_ref, y_ref, o_ref, carry_ref, *, acc_dtype, fold_beta):
-    kk = pl.program_id(1)
-    nn = pl.program_id(2)
-    a = a_ref[...].astype(acc_dtype)            # (bm, bk)
-    y = y_ref[...].astype(acc_dtype)            # (bk, bn) weight deltas
-    part = ffip_tile(a, y, carry_ref, nn, fold_beta=fold_beta)
+def _kernel(ao_ref, ae_ref, yo_ref, ye_ref, o_ref, *, acc_dtype, fold_beta):
+    kk = pl.program_id(2)
+    part = ffip_tile(ao_ref[...].astype(acc_dtype),
+                     ae_ref[...].astype(acc_dtype),
+                     yo_ref[...].astype(acc_dtype),
+                     ye_ref[...].astype(acc_dtype), fold_beta=fold_beta)
 
     @pl.when(kk == 0)
     def _init():
@@ -90,40 +108,41 @@ def _kernel(a_ref, y_ref, o_ref, carry_ref, *, acc_dtype, fold_beta):
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret",
                                              "fold_beta"))
 def ffip_gemm_y(a: Array, y: Array, *, bm: int = 128, bn: int = 128,
-                bk: int = 64, interpret=None,
+                bk: int = 256, interpret=None,
                 fold_beta: bool = False) -> Array:
     """FFIP GEMM from precomputed y deltas. a: (M, K), y: (K, N) -> (M, N).
 
     Non-divisible shapes zero-pad and slice (exact for the returned corner:
     zero y rows reconstruct zero b rows against zero a columns, and padded N
-    columns live at the tail of the final carry sweep so no real column reads
-    their prefix). bk must be even; ``interpret=None`` = backend auto."""
+    columns only repeat the last real column of b). bk must be even (bk/2
+    pairs per block); ``interpret=None`` = backend auto."""
     interpret = resolve_interpret(interpret)
-    assert bk % 2 == 0
+    if bk % 2:
+        raise ValueError(f"bk={bk} must be even for the FIP pair algebra")
     m0, k0 = a.shape
     k2, n0 = y.shape
-    assert k0 == k2
-    a, y = pad_to_blocks(a, y, bm, bn, bk)
-    m, k = a.shape
-    n = y.shape[1]
+    if k0 != k2:
+        raise ValueError(f"contraction mismatch {k0} vs {k2}")
+    bkh = bk // 2
+    m, n = -(-m0 // bm) * bm, -(-n0 // bn) * bn
+    kh = -(-k0 // bk) * bkh
+    ao, ae = (pad_to(x, (m, kh)) for x in deinterleave(a, 1))
+    yb = block_deltas(pad_to(y, (k0, n)), bn)
+    yo, ye = (pad_to(x, (kh, n)) for x in deinterleave(yb, 0))
     acc_dtype = (jnp.int32 if jnp.issubdtype(a.dtype, jnp.integer)
                  else jnp.float32)
-    # grid: N innermost so the carry sweeps columns for a fixed (m, k) stripe.
-    grid = (m // bm, k // bk, n // bn)
+    a_spec = pl.BlockSpec((bm, bkh), lambda i, j, kk: (i, kk))
+    y_spec = pl.BlockSpec((bkh, bn), lambda i, j, kk: (kk, j))
     out = pl.pallas_call(
         functools.partial(_kernel, acc_dtype=acc_dtype, fold_beta=fold_beta),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, kk, j: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, kk, j: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, kk, j: (i, j)),
+        grid=(m // bm, n // bn, kh // bkh),
+        in_specs=[a_spec, a_spec, y_spec, y_spec],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), acc_dtype),
-        scratch_shapes=[pltpu.VMEM((bk, 1), acc_dtype)],
         compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(a, y)
+    )(ao, ae, yo, ye)
     return out[:m0, :n0]
 
 

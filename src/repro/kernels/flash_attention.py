@@ -73,7 +73,7 @@ def _fwd_kernel(w_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     def _fin():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[...][:, 0] + jnp.log(l[:, 0]))
+        lse_ref[0] = m_scr[...] + jnp.log(l)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk",
@@ -81,7 +81,9 @@ def _fwd_kernel(w_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 def _flash_fwd(q, k, v, window, *, causal=True, bq=128, bk=128,
                interpret=None) -> Tuple[Array, Array]:
     """q: (BH, Sq, d), k/v: (BH, Sk, d), window: () int32 (traced OK, <=0 =
-    full) -> (out (BH,Sq,d), lse (BH,Sq))."""
+    full) -> (out (BH,Sq,d), lse (BH,Sq,1)). lse keeps a trailing unit dim:
+    its (1, bq, 1) block spans the whole minor dim, which TPU accepts, where
+    a (1, bq) block of a (BH, Sq) array would put bq on the lanes."""
     interpret = resolve_interpret(interpret)
     window = jnp.asarray(window, jnp.int32).reshape(1, 1)
     bh, sq, d = q.shape
@@ -111,11 +113,11 @@ def _flash_fwd(q, k, v, window, *, causal=True, bq=128, bk=128,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq_pad, dv), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq_pad), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sq_pad, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -142,7 +144,7 @@ def _bwd_kernel(w_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
     v = v_ref[0]
     do = do_ref[0].astype(jnp.float32)
     o = o_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]                                # (bq,)
+    lse = lse_ref[0]                                # (bq, 1)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -153,7 +155,7 @@ def _bwd_kernel(w_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
         mask = jnp.logical_and(mask, q_pos >= k_pos)
     w = w_ref[0, 0]
     mask = jnp.logical_and(mask, jnp.logical_or(w <= 0, q_pos - k_pos < w))
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)   # (bq, bk)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)            # (bq, bk)
 
     delta = jnp.sum(do * o, axis=1, keepdims=True)        # (bq, 1)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
@@ -204,7 +206,8 @@ def _flash_bwd(q, k, v, o, lse, do, window, *, causal=True, bq=128, bk=128,
         q = jnp.pad(q, pad)
         o = jnp.pad(o, pad)
         do = jnp.pad(do, pad)
-        lse = jnp.pad(lse, ((0, 0), (0, sq_pad - sq)), constant_values=1e30)
+        lse = jnp.pad(lse, ((0, 0), (0, sq_pad - sq), (0, 0)),
+                      constant_values=1e30)
     if sk_pad != sk:
         pad = ((0, 0), (0, sk_pad - sk), (0, 0))
         k = jnp.pad(k, pad)
@@ -225,7 +228,7 @@ def _flash_bwd(q, k, v, o, lse, do, window, *, causal=True, bq=128, bk=128,
             pl.BlockSpec((1, bk, dv), lambda b, j, i: (b, j, 0)),  # v
             pl.BlockSpec((1, bq, dv), lambda b, j, i: (b, i, 0)),  # do
             pl.BlockSpec((1, bq, dv), lambda b, j, i: (b, i, 0)),  # o
-            pl.BlockSpec((1, bq), lambda b, j, i: (b, i)),         # lse
+            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),   # lse
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),   # dq

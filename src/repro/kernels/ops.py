@@ -1,12 +1,12 @@
 """Public jit'd wrappers over the Pallas GEMM kernels.
 
 Handles: leading batch dims, dtype policy (int8→int32 accumulation,
-bf16→f32), default block selection for VMEM fit (:func:`choose_blocks`),
-and output casting. Padding to block multiples lives in the kernels
-themselves (``baseline_gemm.pad_to_blocks`` — zero rows/cols are exact for
-the baseline products and the FIP/FFIP cross/α/β algebra), so any caller —
-this wrapper, the repro.tune measurement harness, or a direct kernel user —
-gets the same pad-run-slice fallback.
+bf16→f32), default block selection (:func:`choose_blocks`: TPU-legal and
+VMEM-bounded), and output casting. Padding to block multiples lives in the
+kernels themselves (``baseline_gemm.pad_to_blocks``, ``fip_gemm.pad_to`` —
+zero rows/cols are exact for the baseline products and the FIP/FFIP
+cross/α/β algebra), so any caller — this wrapper, the repro.tune measurement
+harness, or a direct kernel user — gets the same pad-run-slice fallback.
 """
 from __future__ import annotations
 
@@ -17,39 +17,51 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.baseline_gemm import baseline_gemm
-# Public surface for the Pallas API-drift shim (kernel modules import it from
-# repro.kernels.compat to avoid a circular import with this module).
-from repro.kernels.compat import resolve_interpret, tpu_compiler_params  # noqa: F401
-from repro.kernels.fip_gemm import fip_gemm
+from repro.kernels.compat import resolve_interpret
+from repro.kernels.fip_gemm import PAIR_CHUNK, fip_gemm
 from repro.kernels.ffip_gemm import ffip_gemm
 from repro.obs import profile as _obs_profile
 
 Array = jax.Array
 
-# VMEM budget per operand block (bytes) used by the block chooser. A v5e core
-# has ~16 MiB VMEM; the FIP cross tensor is (bm, bk/2, bn) so bk is the lever.
+# Scoped-VMEM budget a tile may plan for (bytes). A v5e core has 16 MiB of
+# scoped VMEM by default; the rest is headroom for Mosaic's own buffers.
 _VMEM_BUDGET = 6 * 1024 * 1024
+# TPU block rule: a block's minor dimension is a multiple of LANE and its
+# second-minor a multiple of SUBLANE, unless the block spans the whole
+# (padded) dimension — which is what a block >= the problem dimension gives,
+# since the kernels pad every operand to block multiples.
+LANE = 128
+SUBLANE = 8
 
 
-def choose_blocks(m: int, n: int, k: int, algo: str,
-                  itemsize: int = 4) -> Tuple[int, int, int]:
+def tile_vmem_bytes(bm: int, bn: int, bk: int, algo: str,
+                    itemsize: int = 4) -> int:
+    """VMEM one grid step plans for: double-buffered operand blocks, the
+    double-buffered f32/int32 output block, and for the FIP family the
+    (bm, PAIR_CHUNK, bn) pre-add/product intermediates of the pair loop."""
+    total = 2 * (bm * bk + bk * bn) * itemsize + 2 * bm * bn * 4
+    if algo != "baseline":
+        total += 3 * bm * PAIR_CHUNK * bn * 4
+    return total
+
+
+def choose_blocks(m: int, n: int, k: int, algo: str) -> Tuple[int, int, int]:
+    """Static default (bm, bn, bk): TPU-legal for any problem shape (see
+    LANE/SUBLANE) and within the VMEM budget. For the FIP family bk/2 pairs
+    make up one lane block of each deinterleaved half."""
     bm = min(128, _round_up_pow2(m))
-    bn = min(128, _round_up_pow2(n))
+    bn = min(LANE, _round_up_pow2(n))
     if algo == "baseline":
-        bk = min(512, _round_up_pow2(k))
+        bk = min(4 * LANE, _round_up_pow2(k))
     else:
-        # fit 3 x (bm, bk/2, bn) f32 tensors in budget
-        bk = 8
-        while (3 * bm * bn * (bk) // 2 * itemsize) <= _VMEM_BUDGET and bk < 256:
-            bk *= 2
-        bk //= 2
-        bk = max(2, min(bk, _round_up_pow2(k)))
+        bk = 2 * min(LANE, _round_up_pow2(-(-k // 2)))
     return bm, bn, bk
 
 
 def _round_up_pow2(x: int) -> int:
-    p = 8
-    while p < x and p < 1024:
+    p = SUBLANE
+    while p < x:
         p *= 2
     return p
 

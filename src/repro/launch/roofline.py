@@ -17,6 +17,8 @@ import json
 import re
 from typing import Dict, List, Optional, Tuple
 
+from repro.core import analytical
+
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3": 1, "f8e5m2": 1,
     "s64": 8, "u64": 8, "s32": 4, "u32": 4, "s16": 2, "u16": 2,
@@ -175,12 +177,15 @@ def remat_duplication(hlo_text: str) -> float:
 
 
 def roofline_report(flops: float, hlo_bytes: float, coll: CollectiveStats,
-                    chips: int, *, peak_flops: float = 197e12,
-                    hbm_bw: float = 819e9, ici_bw: float = 50e9,
+                    chips: int, *,
+                    device_kind: str = analytical.DRYRUN_DEVICE_KIND,
                     model_flops: Optional[float] = None) -> dict:
-    compute_s = flops / (chips * peak_flops)
-    memory_s = hlo_bytes / (chips * hbm_bw)
-    collective_s = coll.total_bytes / (chips * ici_bw)
+    """Roofline terms against the published peaks of ``device_kind``
+    (core.analytical.CHIP_PEAKS; an unknown kind raises)."""
+    peaks = analytical.chip_peaks(device_kind)
+    compute_s = flops / (chips * peaks.bf16_flops)
+    memory_s = hlo_bytes / (chips * peaks.hbm_bw)
+    collective_s = coll.total_bytes / (chips * peaks.ici_bw)
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     dominant = max(terms, key=terms.get)

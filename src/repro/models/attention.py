@@ -174,30 +174,53 @@ def _flash_sdpa(q: Array, k: Array, v: Array, window, causal: bool) -> Array:
     mesh = dctx.get_mesh()
     if mesh is None:
         out = flash_attention(qt, kt, vt, w, causal, interp, bq, bk)
-    else:
-        # shard_map over the fused (B*H) dim: flash is embarrassingly parallel
-        # there; each device runs the kernel on its local rows with ZERO
-        # collectives (without this, the SPMD partitioner gathers q/k/v around
-        # the interpret-mode kernel — §Perf starcoder2 iter-1 found 88TB of
-        # wire traffic).
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
-        bh = b * h
-        batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-        ladder = [batch_axes + (("model",) if "model" in mesh.axis_names else ()),
-                  batch_axes, ()]
-        axis_size = dict(zip(mesh.axis_names, mesh.devices.shape))
-        spec_axes = next(axes for axes in ladder
-                         if bh % max(1, int(np.prod([axis_size[a] for a in axes]
-                                                    or [1]))) == 0)
-        sp = P(spec_axes if spec_axes else None, None, None)
-        out = shard_map(
-            lambda q_, k_, v_, w_: flash_attention(q_, k_, v_, w_, causal,
-                                                   interp, bq, bk),
-            mesh=mesh, in_specs=(sp, sp, sp, P()), out_specs=sp,
-            check_rep=False,
-        )(qt, kt, vt, jnp.asarray(w, jnp.int32))
-    dv = out.shape[-1]   # MLA: value dim differs from q/k head dim
+        dv = out.shape[-1]   # MLA: value dim differs from q/k head dim
+        return out.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
+    # shard_map: flash is embarrassingly parallel over (batch, head); each
+    # device runs the kernel on its local rows with ZERO collectives (without
+    # this, the SPMD partitioner gathers q/k/v around the kernel — §Perf
+    # starcoder2 iter-1 found 88TB of wire traffic).
+    from jax.sharding import PartitionSpec as P
+    axis_size = dict(zip(mesh.axis_names, mesh.devices.shape))
+    batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+
+    def divides(n, axes):
+        return n % int(np.prod([axis_size[a] for a in axes] or [1])) == 0
+
+    bax = (batch_axes or None) if divides(b, batch_axes) else None
+    w = jnp.asarray(w, jnp.int32)
+    if "model" in axis_size and divides(h, ("model",)):
+        # heads over "model", batch over the batch axes: the layout the
+        # tensor-parallel projections already give q/k/v, so nothing moves
+        # and the rest of the layer keeps its batch whole on every device
+        # (sharding the fused B*H dim instead splits the batch, and the
+        # partitioner then all-gathers every weight to follow it).
+        sp = P(bax, "model", None, None)
+
+        def local(q_, k_, v_, w_):
+            lb, lh = q_.shape[:2]
+            o = flash_attention(*(x.reshape(lb * lh, *x.shape[2:])
+                                  for x in (q_, k_, v_)),
+                                w_, causal, interp, bq, bk)
+            return o.reshape(lb, lh, *o.shape[1:])
+
+        out = jax.shard_map(local, mesh=mesh, in_specs=(sp, sp, sp, P()),
+                            out_specs=sp, check_vma=False)(
+            *(x.reshape(b, h, *x.shape[1:]) for x in (qt, kt, vt)), w)
+        return out.transpose(0, 2, 1, 3)
+    # heads do not divide the model axis: shard the fused (B*H) dim
+    bh = b * h
+    ladder = [batch_axes + (("model",) if "model" in axis_size else ()),
+              batch_axes, ()]
+    spec_axes = next(axes for axes in ladder if divides(bh, axes))
+    sp = P(spec_axes if spec_axes else None, None, None)
+    out = jax.shard_map(
+        lambda q_, k_, v_, w_: flash_attention(q_, k_, v_, w_, causal,
+                                               interp, bq, bk),
+        mesh=mesh, in_specs=(sp, sp, sp, P()), out_specs=sp,
+        check_vma=False,
+    )(qt, kt, vt, w)
+    dv = out.shape[-1]
     return out.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
 
 

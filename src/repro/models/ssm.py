@@ -153,7 +153,6 @@ def _selective_scan_fused(xs, dt, bmat, cmat, A, h0, chunk, *,
     if mesh is None:
         out = call(xs, dt, bmat, cmat, A, h0)
         return (out, None) if trainable else out
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     axis_size = dict(zip(mesh.axis_names, mesh.devices.shape))
     baxes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -162,9 +161,9 @@ def _selective_scan_fused(xs, dt, bmat, cmat, A, h0, chunk, *,
     sx = P(bshard, None, dshard)
     sn = P(bshard, None, None)
     in_specs = (sx, sx, sn, sn, P(dshard, None), P(bshard, dshard, None))
-    out = shard_map(call, mesh=mesh, in_specs=in_specs,
-                    out_specs=sx if trainable else (sx, P(bshard, dshard, None)),
-                    check_rep=False)(xs, dt, bmat, cmat, A, h0)
+    out = jax.shard_map(call, mesh=mesh, in_specs=in_specs,
+                        out_specs=sx if trainable else (sx, P(bshard, dshard, None)),
+                        check_vma=False)(xs, dt, bmat, cmat, A, h0)
     return (out, None) if trainable else out
 
 

@@ -194,10 +194,10 @@ class BatchServer:
         # parallel projections + KV-head sharding on the "model" axis,
         # expert- or ffn-parallel MoE banks per `moe_partition`) and every
         # dispatch traces under the ambient mesh so flash attention's
-        # shard_map engages. The specs never split a kernel's K contraction
-        # in integer paths, so int8-FFIP decode stays bit-exact; output
-        # TOKENS are identical to single-device for float too (launch/serve
-        # --compare-single-device asserts it end to end).
+        # shard_map engages. int8 output tokens match single-device on the
+        # CPU and on TPU (chip_smoke.py --chips 4); float tokens match on the
+        # CPU only: on TPU the row-parallel projections sum bf16 partials
+        # across chips in another order than one chip's accumulator.
         self.mesh = mesh
         self.moe_partition = moe_partition
         self.prepared = prepared

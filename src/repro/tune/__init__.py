@@ -162,7 +162,7 @@ def tune_gemm(m: int, n: int, k: int, dtype, *, algo: str = "ffip",
     if entry is not None:
         return entry
     mb, nb, kb = _bucket(m, n, k)
-    cands = space.gemm_candidates(mb, nb, kb, algo)
+    cands = space.gemm_candidates(mb, nb, kb, algo, jnp.dtype(dtype).itemsize)
     if budget:
         cands = cands[:budget]
     best, best_t, trace = measure.best_gemm_blocks(
@@ -210,7 +210,8 @@ def tune_conv(batch: int, h: int, w: int, cin: int, cout: int, kh: int,
     entry = None if force else cache.lookup(key)
     if entry is not None:
         return entry
-    cands = space.conv_candidates(m, n, k, ckw, algo)
+    cands = space.conv_candidates(m, n, k, ckw, algo,
+                                  jnp.dtype(dtype).itemsize)
     if budget:
         cands = cands[:budget]
     best, best_t, trace = measure.best_conv_blocks(

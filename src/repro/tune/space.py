@@ -6,11 +6,14 @@ block-shape candidates per kernel, deterministically ordered so a tuning run
 is reproducible and a budget-limited run always tries the same prefix.
 
 Legality encodes each kernel's real constraints:
-  * GEMM (baseline/fip/ffip): power-of-2 blocks within TPU-friendly bounds,
-    ``bk`` even for the FIP-family pair algebra (Eq. 2 consumes k in pairs),
-    and the FIP cross tensor ``3 x (bm, bk/2, bn)`` f32 fitting the per-core
-    VMEM budget (the kernels pad non-divisible shapes, so divisibility of the
-    problem shape is NOT a constraint — only block legality is);
+  * GEMM (baseline/fip/ffip): power-of-2 blocks that obey the TPU block rule
+    (minor block dim a multiple of 128 lanes, second-minor a multiple of the
+    dtype's sublane tile, unless the block covers the whole padded problem
+    dim), ``bk`` even for the FIP-family pair algebra (Eq. 2 consumes k in
+    pairs, bk/2 pairs per lane block of each deinterleaved half), and the
+    tile's planned VMEM (``ops.tile_vmem_bytes``) within the budget. The
+    kernels pad non-divisible shapes, so divisibility of the problem shape is
+    NOT a constraint;
   * flash attention: (bq, bk) power-of-2 sequence blocks; the head dim rides
     along untiled.
 
@@ -35,7 +38,7 @@ Blocks = Tuple[int, int, int]
 GEMM_BM = (8, 16, 32, 64, 128, 256)
 GEMM_BN = (32, 64, 128, 256)
 GEMM_BK_BASELINE = (32, 64, 128, 256, 512)
-GEMM_BK_FIP = (8, 16, 32, 64, 128, 256)        # even: Eq. 2 pairs
+GEMM_BK_FIP = (8, 16, 32, 64, 128, 256, 512)   # even: Eq. 2 pairs
 FLASH_BQ = (64, 128, 256)
 FLASH_BK = (64, 128, 256)
 
@@ -47,24 +50,24 @@ def round_up_pow2(x: int, lo: int = 8) -> int:
     return p
 
 
-def gemm_block_legal(bm: int, bn: int, bk: int, algo: str,
-                     itemsize: int = 4) -> bool:
-    """Kernel-level legality of a (bm, bn, bk) block for ``algo``."""
+def gemm_block_legal(bm: int, bn: int, bk: int, algo: str, m: int, n: int,
+                     k: int, itemsize: int = 4) -> bool:
+    """TPU legality of a (bm, bn, bk) block for an (m, k) x (k, n) ``algo``
+    GEMM with ``itemsize``-byte operands."""
+    def tiled(block: int, dim: int, mult: int) -> bool:
+        return block % mult == 0 or block >= dim
+
     if min(bm, bn, bk) < 2:
         return False
+    sublane = kops.SUBLANE * max(1, 4 // itemsize)   # packed dtypes: 16, 32
+    if not (tiled(bm, m, sublane) and tiled(bn, n, kops.LANE)):
+        return False
     if algo in ("fip", "ffip"):
-        if bk % 2 != 0:
+        if bk % 2 or not tiled(bk // 2, -(-k // 2), kops.LANE):
             return False
-        # the pre-add cross tensor is (bm, bk/2, bn); the kernel materializes
-        # ~3 of them (g1, g2, product) in VMEM — same budget ops.choose_blocks
-        # enforces for the static default.
-        if 3 * bm * bn * (bk // 2) * itemsize > kops._VMEM_BUDGET:
-            return False
-    else:
-        # baseline: operand + accumulator blocks in VMEM
-        if (bm * bk + bk * bn + bm * bn) * itemsize > kops._VMEM_BUDGET:
-            return False
-    return True
+    elif not tiled(bk, k, kops.LANE):
+        return False
+    return kops.tile_vmem_bytes(bm, bn, bk, algo, itemsize) <= kops._VMEM_BUDGET
 
 
 def gemm_candidates(m: int, n: int, k: int, algo: str,
@@ -84,8 +87,8 @@ def gemm_candidates(m: int, n: int, k: int, algo: str,
         for bm in GEMM_BM if bm <= bm_cap
         for bn in GEMM_BN if bn <= bn_cap
         for bk in bks if bk <= bk_cap
-        if gemm_block_legal(bm, bn, bk, algo, itemsize)]
-    default = tuple(kops.choose_blocks(m, n, k, algo, itemsize))
+        if gemm_block_legal(bm, bn, bk, algo, m, n, k, itemsize)]
+    default = tuple(kops.choose_blocks(m, n, k, algo))
 
     def dist(c):
         return sum(abs(x.bit_length() - d.bit_length())
@@ -111,7 +114,9 @@ def conv_candidates(m: int, n: int, k: int, ckw: int, algo: str,
     ckw = max(1, ckw)
     aligned = []
     mult = ckw
-    while mult <= min(k, max(GEMM_BK_BASELINE)):
+    # up to the first EVEN multiple that spans all of K (a single k block,
+    # legal on TPU for any K)
+    while mult <= max(GEMM_BK_BASELINE) and mult < k + 2 * ckw:
         if mult % 2 == 0 or algo == "baseline":
             aligned.append(mult)
         mult += ckw
@@ -125,8 +130,8 @@ def conv_candidates(m: int, n: int, k: int, ckw: int, algo: str,
         for bm in GEMM_BM if bm <= bm_cap
         for bn in GEMM_BN if bn <= bn_cap
         for bk in bk_axis
-        if gemm_block_legal(bm, bn, bk, algo, itemsize)]
-    default = tuple(kops.choose_blocks(m, n, k, algo, itemsize))
+        if gemm_block_legal(bm, bn, bk, algo, m, n, k, itemsize)]
+    default = tuple(kops.choose_blocks(m, n, k, algo))
 
     def dist(c):
         return sum(abs(x.bit_length() - d.bit_length())

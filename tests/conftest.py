@@ -1,35 +1,9 @@
-"""Test bootstrap: src-layout path + gated dev-dependency fallbacks.
-
-1. Puts `src/` on sys.path so `python -m pytest` works with or without
-   PYTHONPATH=src (the tier-1 command in ROADMAP.md sets it; CI and bare
-   local runs may not).
-2. Install-checks the declared dev dependencies (pyproject.toml). `pytest`
-   is trivially present; if `hypothesis` is missing — this container cannot
-   pip install — a minimal deterministic stand-in
-   (repro._compat.hypothesis_mini) is registered in sys.modules BEFORE test
-   modules import it, so the property tests collect and run everywhere
-   instead of erroring at collection time. Real hypothesis, when installed,
-   always wins.
-"""
+"""Test bootstrap: puts `src/` on sys.path so `python -m pytest` works with
+or without PYTHONPATH=src (the tier-1 command in ROADMAP.md sets it; CI and
+bare local runs may not)."""
 import pathlib
 import sys
 
 _SRC = pathlib.Path(__file__).resolve().parents[1] / "src"
 if str(_SRC) not in sys.path:
     sys.path.insert(0, str(_SRC))
-
-try:
-    import hypothesis  # noqa: F401  (real package present — use it)
-except ImportError:
-    from repro._compat import hypothesis_mini
-
-    sys.modules["hypothesis"] = hypothesis_mini
-    sys.modules["hypothesis.strategies"] = hypothesis_mini.strategies
-
-
-def pytest_report_header(config):
-    impl = sys.modules.get("hypothesis")
-    mini = getattr(impl, "__version__", "") == "0.0-repro-mini"
-    return ("hypothesis: repro._compat.hypothesis_mini fallback "
-            "(pip install hypothesis for full property coverage)"
-            if mini else f"hypothesis: {impl.__version__}")

@@ -124,3 +124,25 @@ def test_tpu_roofline_terms():
     t = an.tpu_roofline_terms(1e15, 1e12, 1e11, 256)
     assert t["bottleneck"] == "compute_s"
     assert t["compute_s"] == pytest.approx(1e15 / (256 * 197e12))
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    peaks = an.chip_peaks("TPU v5 lite")
+    assert (peaks.bf16_flops, peaks.int8_ops, peaks.hbm_bw) == (197e12, 393e12, 819e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        an.chip_peaks("cpu")
+    with pytest.raises(ValueError):
+        an.tpu_roofline_terms(1e15, 1e12, 1e11, 4, device_kind="TPU v9")
+
+
+def test_collective_term_uses_per_chip_interconnect():
+    """The collective term divides by one chip's whole interconnect, 1,600
+    Gbit/s = 200 GB/s on v5e (not one 50 GB/s link): 4e11 bytes over 4
+    chips take 0.5 s."""
+    from repro.launch import roofline
+    t = an.tpu_roofline_terms(0.0, 0.0, 4e11, 4)
+    assert t["collective_s"] == pytest.approx(0.5)
+    stats = roofline.CollectiveStats(counts={"all-reduce": 1},
+                                     bytes_by_kind={"all-reduce": 4e11})
+    r = roofline.roofline_report(0.0, 0.0, stats, 4)
+    assert r["collective_s"] == pytest.approx(0.5)

@@ -82,6 +82,20 @@ def test_ffip_y_operand_never_materializes_b():
     np.testing.assert_array_equal(np.asarray(got, np.int64), want)
 
 
+@pytest.mark.parametrize("bn", [1, 4, 12])
+def test_block_deltas_restart_each_column_block_from_b(bn):
+    """Each bn-column block of the re-encoded deltas is a self-contained Eq. 9
+    encoding: its prefix sum rebuilds that block of B with no state carried
+    in from the blocks before it, so FFIP tiles are independent."""
+    from repro.kernels.ffip_gemm import block_deltas
+    _, b = make_inputs(4, 6, 24, jnp.int8, seed=6)
+    b = b.astype(jnp.int32).reshape(2, 3, 24)          # stacked leading dim
+    yb = block_deltas(jax.vmap(fip.make_y)(b), bn)
+    blocks = np.asarray(yb).reshape(2, 3, 24 // bn, bn)
+    rebuilt = np.cumsum(blocks, axis=-1).reshape(2, 3, 24)
+    np.testing.assert_array_equal(rebuilt, np.asarray(b))
+
+
 def test_ffip_y_memoized_per_weight(monkeypatch):
     """The paper deploys y as an OFFLINE weight transform (§4.4): repeated
     eager ffip_gemm calls against the same weight array derive y once, and a
@@ -159,7 +173,7 @@ def test_kernel_interpret_auto_default():
 
 
 # --- Pallas API-drift canary --------------------------------------------------
-# pltpu.CompilerParams/TPUCompilerParams has already been renamed once across
+# The Pallas TPU compiler-params class has already been renamed once across
 # JAX releases. Build AND run every kernel entry point in interpret mode so
 # the next API break surfaces here, at unit-test cost, instead of deep inside
 # a smoke or system test.
@@ -243,13 +257,13 @@ _DRIFT_CASES = {
 
 
 def test_compiler_params_compat_alias():
-    """The shim resolves whichever spelling the installed Pallas exposes."""
+    """Every kernel's compiler params are the installed Pallas's
+    CompilerParams, with the grid semantics passed through."""
     from jax.experimental.pallas import tpu as pltpu
     from repro.kernels.compat import tpu_compiler_params
-    assert hasattr(pltpu, "CompilerParams") or hasattr(pltpu, "TPUCompilerParams")
     params = tpu_compiler_params(
         dimension_semantics=("parallel", "arbitrary"))
-    assert params is not None
+    assert isinstance(params, pltpu.CompilerParams)
     assert tuple(params.dimension_semantics) == ("parallel", "arbitrary")
 
 

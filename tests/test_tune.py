@@ -52,10 +52,15 @@ def test_candidates_legal_and_deterministic(algo, m, k, n):
         "static default must be candidate 0"
     assert len(c1) == len(set(c1)), "duplicate candidates"
     for bm, bn, bk in c1:
-        assert space.gemm_block_legal(bm, bn, bk, algo), (bm, bn, bk)
+        assert space.gemm_block_legal(bm, bn, bk, algo, m, n, k), (bm, bn, bk)
+        assert bm % 8 == 0 or bm >= m, "sublane tile or whole dim"
+        assert bn % 128 == 0 or bn >= n, "lane tile or whole dim"
         if algo in ("fip", "ffip"):
             assert bk % 2 == 0, "FIP pair algebra needs even bk"
-            assert 3 * bm * bn * (bk // 2) * 4 <= ops._VMEM_BUDGET
+            assert (bk // 2) % 128 == 0 or bk >= k, "pair half: lane tile"
+        else:
+            assert bk % 128 == 0 or bk >= k
+        assert ops.tile_vmem_bytes(bm, bn, bk, algo) <= ops._VMEM_BUDGET
         assert bm <= space.round_up_pow2(m)
         assert bn <= space.round_up_pow2(n)
         assert bk <= space.round_up_pow2(k)

@@ -1,0 +1,14 @@
+"""Model step: useful operations of the traced prefill dispatches (real
+prompt tokens only) over the device time of the prefill program times the
+peak for the tier's arithmetic."""
+from __future__ import annotations
+
+from harness.readers import PREFILL_PROGRAM, dispatches, share
+
+
+def read(rec):
+    ops = secs = 0.0
+    for s, pf in dispatches(rec, PREFILL_PROGRAM):
+        ops += pf["flops"]
+        secs += s
+    return share(ops, secs * rec["peaks"].compute(rec["tier"]))

@@ -17,13 +17,22 @@ across two replicas reads as one tree:
     └─ done
 
 Bounded by construction: completed spans land in a ``deque(maxlen=capacity)``
-ring buffer (a long-running server cannot leak through its own telemetry —
-the failure mode of the old append-only ``BatchServer.events`` list this
-replaces). Spans still open when the ring wraps are kept until ended.
+ring buffer (a long-running server cannot leak through its own
+telemetry). Spans still open when the ring wraps are kept until ended.
 
 Time comes from the injected clock (defaults to the process clock in
 :mod:`repro.obs`), so FakeClock-driven fault tests produce deterministic
-timestamps. Export: :meth:`Tracer.to_jsonl` (one span per line) and
+timestamps.
+
+Spans opened with :meth:`Tracer.span` as context managers nest lexically: a
+span opened inside another's ``with`` block takes it as its parent unless
+one is given. With an ``annotate`` factory each such span also opens
+``annotate(name)`` for its extent, e.g. a profiler annotation that puts the
+span on the same clock as a device trace; :meth:`Tracer.start` /
+:meth:`Tracer.end` spans, which may cross calls, are not mirrored. This
+module imports no profiler itself.
+
+Export: :meth:`Tracer.to_jsonl` (one span per line) and
 :meth:`Tracer.to_chrome_trace` (Chrome ``trace_event`` JSON — open in
 https://ui.perfetto.dev, spans group per-rid as tracks).
 """
@@ -65,11 +74,12 @@ class Span:
 class _SpanHandle:
     """Context-manager handle returned by :meth:`Tracer.span`."""
 
-    __slots__ = ("tracer", "span")
+    __slots__ = ("tracer", "span", "mirror")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self.tracer = tracer
         self.span = span
+        self.mirror = None
 
     @property
     def sid(self) -> int:
@@ -86,21 +96,31 @@ class _SpanHandle:
         return self.span
 
     def __enter__(self) -> "_SpanHandle":
+        self.tracer._stack.append(self.span)
+        if self.tracer.annotate is not None:
+            self.mirror = self.tracer.annotate(self.span.name)
+            self.mirror.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if exc_type is not None and "error" not in self.span.attrs:
             self.span.attrs["error"] = exc_type.__name__
         self.tracer.end(self.span)
+        self.tracer._stack.pop()
+        if self.mirror is not None:
+            self.mirror.__exit__(exc_type, exc, tb)
         return False
 
 
 class Tracer:
     """Ring-buffer span recorder. ``capacity`` bounds *completed* spans;
-    open spans are tracked separately until ended."""
+    open spans are tracked separately until ended. ``annotate(name)``, if
+    given, returns a context manager entered for the extent of each span
+    opened with :meth:`span` in a ``with`` block."""
 
     def __init__(self, clock: Optional[Callable[[], float]] = None,
-                 capacity: int = 4096):
+                 capacity: int = 4096,
+                 annotate: Optional[Callable[[str], Any]] = None):
         if clock is None:
             from repro.obs import default_clock
             clock = default_clock
@@ -108,6 +128,8 @@ class Tracer:
         self.capacity = capacity
         self.spans: deque[Span] = deque(maxlen=capacity)
         self._open: Dict[int, Span] = {}
+        self._stack: List[Span] = []     # spans entered with `with span()`
+        self.annotate = annotate
         self._next_sid = 1
         self.dropped = 0                 # spans evicted by the ring
 
@@ -133,6 +155,10 @@ class Tracer:
 
     def span(self, name: str, *, parent: Optional[int] = None,
              rid: Optional[str] = None, **attrs) -> _SpanHandle:
+        """A span for a ``with`` block; its parent defaults to the
+        innermost span whose ``with`` block is open."""
+        if parent is None and self._stack:
+            parent = self._stack[-1].sid
         return _SpanHandle(self, self.start(name, parent=parent, rid=rid,
                                             **attrs))
 

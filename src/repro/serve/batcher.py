@@ -87,7 +87,10 @@ class Request:
     t_done: float = 0.0           # set when the request completes (e2e)
     # per-token inter-token latency (seconds): one entry per decoded token
     # after the first, mirroring what lands in serve_itl_window_seconds —
-    # the raw list serve_bench cross-checks the windowed percentiles against
+    # the raw list serve_bench cross-checks the windowed percentiles against.
+    # Each token is charged the time since the request's previous token
+    # reached the host (tokens of one fused chunk share it evenly), so a
+    # prefill that stalls decode shows up here.
     itl_s: Optional[List[float]] = None
 
 
@@ -111,6 +114,14 @@ class _Slot:
     pos: int = 0                  # tokens currently in this slot's cache rows
     remaining: int = 0
     seq: Optional[_PagedSeq] = None   # paged mode only
+    t_tok: float = 0.0            # when the request's latest token reached
+                                  # the host (inter-token latency)
+
+
+def _annotate(name: str):
+    """The profiler annotation that mirrors a batcher span, so a device trace
+    shows what the host was doing on the device's clock."""
+    return jax.profiler.TraceAnnotation("serve." + name)
 
 
 def _cache_batch_axes(model: Model, batch: int, max_len: int):
@@ -209,12 +220,15 @@ class BatchServer:
         self.registry = (registry if registry is not None
                          else obs.get_registry())
         self.tracer = tracer if tracer is not None else Tracer(
-            clock=self._clock, capacity=trace_capacity)
+            clock=self._clock, capacity=trace_capacity, annotate=_annotate)
         # The router relabels per replica via set_obs_labels() and sets
         # trace_requests=False (it owns the per-rid root "request" span —
         # two roots per rid would split the tree).
         self.trace_requests = True
         self._req_spans: Dict[int, Any] = {}
+        # rid -> open "admit_wait" span: submit until the prefill dispatch
+        # that admits the request starts (the batcher's own queue)
+        self._wait_spans: Dict[int, Any] = {}
         self.set_obs_labels({"replica": "solo"})
         self.slots = [_Slot() for _ in range(batch_slots)]
         self._queue: "collections.deque[Request]" = collections.deque()
@@ -406,25 +420,23 @@ class BatchServer:
             window_s=self.obs_window_s, clock=self._clock
         ).labels(replica=rep, tier=self.tier)
 
-    @property
-    def events(self) -> List[Tuple]:
-        """Legacy dispatch-interleaving view, reconstructed from the span
-        ring: ``("prefill_chunk", rid, start, end)`` and
-        ``("decode", (rids...))`` tuples in dispatch order. Bounded by the
-        tracer's ring capacity (the old append-only list grew without limit
-        on long-running servers)."""
-        out: List[Tuple] = []
-        for s in self.tracer.spans:
-            if s.name == "prefill_chunk":
-                out.append(("prefill_chunk", s.attrs["rid_int"],
-                            s.attrs["start"], s.attrs["end"]))
-            elif s.name == "decode" and "rids" in s.attrs:
-                out.append(("decode", tuple(s.attrs["rids"])))
-        return out
-
     def _end_req_span(self, rid: int, **attrs) -> None:
         span = self._req_spans.pop(rid, None)
         if span is not None:
+            self.tracer.end(span, **attrs)
+
+    def _start_wait(self, req: Request) -> None:
+        root = self._req_spans.get(req.rid)
+        self._wait_spans[req.rid] = self.tracer.start(
+            "admit_wait", parent=None if root is None else root.sid,
+            rid=str(req.rid))
+
+    def _end_wait(self, rid: int, t: Optional[float] = None,
+                  **attrs) -> None:
+        """End ``rid``'s ``admit_wait`` span, at ``t`` if given."""
+        span = self._wait_spans.pop(rid, None)
+        if span is not None:
+            span.t1 = t
             self.tracer.end(span, **attrs)
 
     # -- quantized decode mode / mesh scope --------------------------------
@@ -472,6 +484,22 @@ class BatchServer:
         return p
 
     # -- device programs ---------------------------------------------------
+    def _dispatch(self, phase: str, program, params, x, *rest,
+                  sync: bool = True):
+        """Run ``program(params, x, cache, *rest)`` on the server's cache
+        under the GEMM scope (``<phase>.dispatch`` span) and, with ``sync``,
+        bring its token output to the host (``<phase>.sync``: the host
+        blocked on the device). Returns that output (None without ``sync``)
+        and the wall seconds the two took."""
+        t0 = self._clock()
+        with self.tracer.span(phase + ".dispatch"), self._gemm_scope():
+            self.cache, out = program(params, x, self.cache, *rest)
+        out_h = None
+        if sync:
+            with self.tracer.span(phase + ".sync"):
+                out_h = np.asarray(jax.device_get(out))
+        return out_h, self._clock() - t0
+
     def _decode_impl(self, params, last, cache, pos, live, rem, eos):
         self.compiles["decode"] += 1    # side effect runs at trace time only
         self._m_compiles["decode"].inc()
@@ -596,6 +624,7 @@ class BatchServer:
             self._req_spans[req.rid] = self.tracer.start(
                 "request", rid=str(req.rid), prompt=len(req.prompt),
                 max_new_tokens=req.max_new_tokens)
+        self._start_wait(req)
         self._queue.append(req)
 
     def has_queued(self) -> bool:
@@ -655,6 +684,7 @@ class BatchServer:
         for w in self._dup_waiters.pop(rid, []):
             self._queue.appendleft(w)
         if found:
+            self._end_wait(rid, aborted=True)
             self._end_req_span(rid, aborted=True)
         return found
 
@@ -714,6 +744,7 @@ class BatchServer:
         slot.pos = len(req.prompt)   # prompt rows in cache; the first
         slot.remaining = req.max_new_tokens - 1   # generated token is in
         # flight and will be written at row `pos` by the next decode step
+        slot.t_tok = req.t_first
 
     def _admit(self, params):
         if self.paged:
@@ -751,47 +782,46 @@ class BatchServer:
                 kept.append(r)
         self._queue.extendleft(reversed(kept))
 
-        tokens = np.zeros((self.b, bucket), np.int32)
-        lengths = np.ones((self.b,), np.int32)
-        mask = np.zeros((self.b,), bool)
-        for slot_i, req in zip(free, batch):
-            n = len(req.prompt)
-            tokens[slot_i, :n] = req.prompt
-            lengths[slot_i] = n
-            mask[slot_i] = True
-            self.stats["prefill_tokens"] += n
-        span = self.tracer.start("prefill", bucket=bucket,
-                                 rids=[r.rid for r in batch])
-        t0 = self._clock()
-        with self._gemm_scope():
-            self.cache, first = self._prefill_bucket(
-                params, jnp.asarray(tokens), self.cache,
-                jnp.asarray(lengths), jnp.asarray(mask))
-        first_h = np.asarray(jax.device_get(first))     # (B,) int32
-        dt = self._clock() - t0
-        self.tracer.end(span)
+        n_tokens = sum(len(r.prompt) for r in batch)
+        with self.tracer.span("prefill", bucket=bucket,
+                              rids=[r.rid for r in batch],
+                              tokens=n_tokens) as span:
+            with self.tracer.span("prefill.pack"):
+                tokens = np.zeros((self.b, bucket), np.int32)
+                lengths = np.ones((self.b,), np.int32)
+                mask = np.zeros((self.b,), bool)
+                for slot_i, req in zip(free, batch):
+                    n = len(req.prompt)
+                    tokens[slot_i, :n] = req.prompt
+                    lengths[slot_i] = n
+                    mask[slot_i] = True
+                args = (jnp.asarray(tokens), jnp.asarray(lengths),
+                        jnp.asarray(mask))
+            for req in batch:
+                self._end_wait(req.rid, span.span.t0)
+            first_h, dt = self._dispatch("prefill", self._prefill_bucket,
+                                         params, *args)   # (B,) int32
         self.stats["prefill_s"] += dt
+        self.stats["prefill_tokens"] += n_tokens
         self.stats["prefill_dispatches"] += 1
         self.stats["host_bytes_prefill"] += int(first_h.nbytes)
         self._m_dispatch["prefill"].inc()
         self._m_dispatch_s["prefill"].observe(dt)
-        self._m_tokens["prefill"].inc(sum(len(r.prompt) for r in batch))
+        self._m_tokens["prefill"].inc(n_tokens)
         self._m_host_bytes["prefill"].inc(int(first_h.nbytes))
-        for slot_i, req in zip(free, batch):
-            self._place(slot_i, req, int(first_h[slot_i]))
+        with self.tracer.span("place"):
+            for slot_i, req in zip(free, batch):
+                self._place(slot_i, req, int(first_h[slot_i]))
 
     def _admit_one(self, params, slot_i: int):
         req = self._queue.popleft()
-        toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
-        span = self.tracer.start("prefill", rid=str(req.rid),
-                                 tokens=len(req.prompt))
-        t0 = self._clock()
-        with self._gemm_scope():
-            self.cache, first = self._prefill_one(params, toks, self.cache,
-                                                  slot_i)
-        first_h = int(jax.device_get(first))
-        dt = self._clock() - t0
-        self.tracer.end(span)
+        with self.tracer.span("prefill", rid=str(req.rid),
+                              tokens=len(req.prompt)) as span:
+            with self.tracer.span("prefill.pack"):
+                toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
+            self._end_wait(req.rid, span.span.t0)
+            first_h, dt = self._dispatch("prefill", self._prefill_one,
+                                         params, toks, slot_i)
         self.stats["prefill_s"] += dt
         self.stats["prefill_tokens"] += len(req.prompt)
         self.stats["prefill_dispatches"] += 1
@@ -800,7 +830,8 @@ class BatchServer:
         self._m_dispatch_s["prefill"].observe(dt)
         self._m_tokens["prefill"].inc(len(req.prompt))
         self._m_host_bytes["prefill"].inc(4)
-        self._place(slot_i, req, first_h)
+        with self.tracer.span("place"):
+            self._place(slot_i, req, int(first_h))
 
     # -- paged mode --------------------------------------------------------
     def _admit_paged(self):
@@ -955,28 +986,26 @@ class BatchServer:
                 continue
             start = seq.compute_next
             end = min(seq.n, (start // chunk + 1) * chunk)
-            self._ensure_pages(slot, max(start, seq.filled), end)
-            tokens = np.zeros((1, chunk), np.int32)
-            tokens[0, :end - start] = slot.req.prompt[start:end]
-            pt = np.zeros((1, self.max_pages), np.int32)
-            pt[0, :len(seq.pages)] = seq.pages
-            span = self.tracer.start("prefill_chunk", rid=str(slot.req.rid),
-                                     rid_int=slot.req.rid, start=start,
-                                     end=end)
-            t0 = self._clock()
-            with self._gemm_scope():
-                self.cache, tok = self._prefill_chunk_fn(
-                    params, jnp.asarray(tokens), self.cache, jnp.asarray(pt),
-                    jnp.asarray(start, jnp.int32),
-                    jnp.asarray(end - start, jnp.int32),
-                    jnp.asarray(seq.filled, jnp.int32))
-            last_chunk = end >= seq.n
-            if last_chunk:                   # token only meaningful here
-                first = int(jax.device_get(tok))
+            last_chunk = end >= seq.n        # token only meaningful here
+            with self.tracer.span("prefill_chunk", rid=str(slot.req.rid),
+                                  rid_int=slot.req.rid, start=start,
+                                  end=end) as span:
+                with self.tracer.span("prefill.pack"):
+                    self._ensure_pages(slot, max(start, seq.filled), end)
+                    tokens = np.zeros((1, chunk), np.int32)
+                    tokens[0, :end - start] = slot.req.prompt[start:end]
+                    pt = np.zeros((1, self.max_pages), np.int32)
+                    pt[0, :len(seq.pages)] = seq.pages
+                    args = (jnp.asarray(tokens), jnp.asarray(pt),
+                            jnp.asarray(start, jnp.int32),
+                            jnp.asarray(end - start, jnp.int32),
+                            jnp.asarray(seq.filled, jnp.int32))
+                self._end_wait(slot.req.rid, span.span.t0)
+                tok_h, dt = self._dispatch("prefill", self._prefill_chunk_fn,
+                                           params, *args, sync=last_chunk)
+            if last_chunk:
                 self.stats["host_bytes_prefill"] += 4
                 self._m_host_bytes["prefill"].inc(4)
-            dt = self._clock() - t0
-            self.tracer.end(span)
             self.stats["prefill_s"] += dt
             self.stats["prefill_tokens"] += end - start
             self.stats["prefill_dispatches"] += 1
@@ -991,7 +1020,8 @@ class BatchServer:
             self._register_prefix(seq, seq.filled)
             work += 1
             if last_chunk:
-                self._place(slot_i, slot.req, first)
+                with self.tracer.span("place"):
+                    self._place(slot_i, slot.req, int(tok_h))
         return work
 
     def _refresh_page_stats(self):
@@ -1005,80 +1035,108 @@ class BatchServer:
         all active slots; in paged mode, preceded by at most one prefill
         CHUNK per mid-prefill slot (chunked prefill interleaves with decode
         instead of stalling it). Returns #active decode slots plus #prefill
-        chunks dispatched."""
-        if self._cached_hits:   # idempotent duplicates: cached completions
-            self._completed.extend(self._cached_hits)
-            self._cached_hits.clear()
-        params = self._params_for(params)
-        self._admit(params)
-        prefill_work = self._prefill_tick(params) if self.paged else 0
-        # mid-prefill paged slots hold remaining == 0 and sit out the decode
-        # dispatch; contiguous occupancy always implies remaining >= 1.
-        active = [i for i, s in enumerate(self.slots)
-                  if s.req is not None and s.remaining > 0]
-        if not active:
+        chunks dispatched.
+
+        Traced as a ``step`` span with children ``params``, ``admit``
+        (``prefill`` / ``prefill_chunk`` with ``.pack`` / ``.dispatch`` /
+        ``.sync`` below, and ``place``), ``decode`` (``.pack`` /
+        ``.dispatch`` / ``.sync``) and ``replay``."""
+        with self.tracer.span("step", queued=len(self._queue),
+                              active=self.b - self.free_slots()):
+            if self._cached_hits:   # idempotent duplicates: cached results
+                self._completed.extend(self._cached_hits)
+                self._cached_hits.clear()
+            with self.tracer.span("params"):
+                params = self._params_for(params)
+            with self.tracer.span("admit"):
+                self._admit(params)
+                prefill_work = self._prefill_tick(params) if self.paged else 0
+            # mid-prefill paged slots hold remaining == 0 and sit out the
+            # decode dispatch; contiguous occupancy always implies
+            # remaining >= 1.
+            active = [i for i, s in enumerate(self.slots)
+                      if s.req is not None and s.remaining > 0]
+            if active:
+                toks_h, t_host = self._decode_dispatch(params, active)
+                with self.tracer.span("replay") as span:
+                    span.set(emitted=self._replay(active, toks_h, t_host))
             if self.paged:
                 self._refresh_page_stats()
-            return prefill_work
-        last = np.zeros((self.b,), np.int32)
-        pos = np.zeros((self.b,), np.int32)
-        live = np.zeros((self.b,), bool)
-        rem = np.zeros((self.b,), np.int32)
-        eos = np.full((self.b,), -1, np.int32)
+            return len(active) + prefill_work
+
+    def _decode_dispatch(self, params, active: List[int]):
+        """The decode dispatch over the ``active`` slots. Returns the
+        ``(chunk, B)`` sampled tokens on the host and when they got there.
+
+        Its ``decode`` span counts ``live_rows``, the K/V rows the active
+        slots attend over the chunk's steps (``pos + 1`` at the first, up to
+        each slot's budget), against ``cache_rows``, the rows the program's
+        cache operand holds (slots x max_len, or the page pool) times the
+        chunk."""
+        k = self.decode_chunk
+        live_rows = 0
         for i in active:
             slot = self.slots[i]
-            last[i] = slot.req.out_tokens[-1]
-            pos[i] = slot.pos
-            live[i] = True
-            rem[i] = slot.remaining
-            eos[i] = slot.req.eos_id
-        # per-slot position vector: slot i writes KV at row pos[i] and masks
-        # rows >= pos[i] + 1; inactive/frozen slots re-write their own row
-        # with unchanged values, so the cache stays bit-identical to
-        # sequential decode across the whole chunk. (Paged mode instead GATES
-        # frozen slots' writes off — pool rows can be shared.)
-        span = self.tracer.start(
-            "decode", rids=[self.slots[i].req.rid for i in active],
-            chunk=self.decode_chunk)
-        if self.paged:
-            for i in active:
-                slot = self.slots[i]
-                self._ensure_pages(slot, slot.pos,
-                                   slot.pos + min(self.decode_chunk,
-                                                  slot.remaining))
-            pt = np.zeros((self.b, self.max_pages), np.int32)
-            for i in active:
-                seq = self.slots[i].seq
-                pt[i, :len(seq.pages)] = seq.pages
-            t0 = self._clock()
-            with self._gemm_scope():
-                self.cache, toks = self._decode_paged(
-                    params, jnp.asarray(last), self.cache,
-                    jnp.asarray(pos), jnp.asarray(live), jnp.asarray(rem),
-                    jnp.asarray(eos), jnp.asarray(pt))
-            self.stats["host_bytes_page_tables"] += int(pt.nbytes)
-            self._m_host_bytes["page_tables"].inc(int(pt.nbytes))
-        else:
-            t0 = self._clock()
-            with self._gemm_scope():
-                self.cache, toks = self._decode(
-                    params, jnp.asarray(last), self.cache,
-                    jnp.asarray(pos), jnp.asarray(live), jnp.asarray(rem),
-                    jnp.asarray(eos))
-        toks_h = np.asarray(jax.device_get(toks))       # (chunk, B) int32
-        dt = self._clock() - t0
-        self.tracer.end(span)
+            n = min(k, slot.remaining)
+            live_rows += n * (slot.pos + 1) + n * (n - 1) // 2
+        rows = (self.num_pages * self.page_size if self.paged
+                else self.b * self.max_len)
+        with self.tracer.span("decode",
+                              rids=[self.slots[i].req.rid for i in active],
+                              chunk=k, live_rows=live_rows,
+                              cache_rows=k * rows):
+            with self.tracer.span("decode.pack"):
+                last = np.zeros((self.b,), np.int32)
+                pos = np.zeros((self.b,), np.int32)
+                live = np.zeros((self.b,), bool)
+                rem = np.zeros((self.b,), np.int32)
+                eos = np.full((self.b,), -1, np.int32)
+                for i in active:
+                    slot = self.slots[i]
+                    last[i] = slot.req.out_tokens[-1]
+                    pos[i] = slot.pos
+                    live[i] = True
+                    rem[i] = slot.remaining
+                    eos[i] = slot.req.eos_id
+                # per-slot position vector: slot i writes KV at row pos[i]
+                # and masks rows >= pos[i] + 1; inactive/frozen slots
+                # re-write their own row with unchanged values, so the cache
+                # stays bit-identical to sequential decode across the whole
+                # chunk. (Paged mode instead GATES frozen slots' writes off —
+                # pool rows can be shared.)
+                args = [jnp.asarray(last), jnp.asarray(pos),
+                        jnp.asarray(live), jnp.asarray(rem), jnp.asarray(eos)]
+                if self.paged:
+                    for i in active:
+                        slot = self.slots[i]
+                        self._ensure_pages(slot, slot.pos,
+                                           slot.pos + min(k, slot.remaining))
+                    pt = np.zeros((self.b, self.max_pages), np.int32)
+                    for i in active:
+                        seq = self.slots[i].seq
+                        pt[i, :len(seq.pages)] = seq.pages
+                    args.append(jnp.asarray(pt))
+                    self.stats["host_bytes_page_tables"] += int(pt.nbytes)
+                    self._m_host_bytes["page_tables"].inc(int(pt.nbytes))
+            toks_h, dt = self._dispatch(
+                "decode", self._decode_paged if self.paged else self._decode,
+                params, *args)                          # (chunk, B) int32
+        t_host = self._clock()
         self.stats["decode_s"] += dt
         self.stats["decode_dispatches"] += 1
         self.stats["host_bytes_decode"] += int(toks_h.nbytes)
         self._m_dispatch["decode"].inc()
         self._m_dispatch_s["decode"].observe(dt)
         self._m_host_bytes["decode"].inc(int(toks_h.nbytes))
-        # replay the device's (eos, remaining) bookkeeping on the host to
-        # recover which of the chunk tokens were actually emitted per slot.
-        # Inter-token attribution: a fused chunk of k steps lands host-side
-        # as one dispatch, so each token in it is charged dt / k.
-        step_dt = dt / toks_h.shape[0]
+        return toks_h, t_host
+
+    def _replay(self, active: List[int], toks_h: np.ndarray,
+                t_host: float) -> int:
+        """Replay the device's (eos, remaining) bookkeeping on the host to
+        recover which of the chunk's tokens each slot emitted; finish the
+        requests that ended. Returns the tokens emitted."""
+        got = dict.fromkeys(active, 0)
+        total = 0
         for j in range(toks_h.shape[0]):
             emitted = 0
             for i in active:
@@ -1087,13 +1145,12 @@ class BatchServer:
                     continue
                 nxt = int(toks_h[j, i])
                 slot.req.out_tokens.append(nxt)
-                self._w_itl.observe(step_dt)
-                if slot.req.itl_s is not None:
-                    slot.req.itl_s.append(step_dt)
                 slot.pos += 1
                 slot.remaining -= 1
+                got[i] += 1
                 emitted += 1
                 if slot.remaining <= 0 or nxt == slot.req.eos_id:
+                    self._charge_itl(slot, got[i], t_host)
                     self._finish(slot.req)
                     if slot.seq is not None:
                         self._release_seq(slot)
@@ -1102,9 +1159,21 @@ class BatchServer:
                 self.stats["steps"] += 1
                 self.stats["decode_tokens"] += emitted
                 self._m_tokens["decode"].inc(emitted)
-        if self.paged:
-            self._refresh_page_stats()
-        return len(active) + prefill_work
+                total += emitted
+        for i in active:
+            if self.slots[i].req is not None:
+                self._charge_itl(self.slots[i], got[i], t_host)
+        return total
+
+    def _charge_itl(self, slot: _Slot, k: int, t_host: float) -> None:
+        """Charge the ``k`` tokens a slot's request just received the time
+        since its previous token reached the host, in equal shares."""
+        gap = (t_host - slot.t_tok) / k
+        slot.t_tok = t_host
+        for _ in range(k):
+            self._w_itl.observe(gap)
+            if slot.req.itl_s is not None:
+                slot.req.itl_s.append(gap)
 
     def run_until_drained(self, params, *, max_steps: int = 10_000,
                           ) -> List[Request]:

@@ -11,7 +11,7 @@ contracts it enforces across the serving stack:
 * profiler: FIP/FFIP multiplier accounting (Eqs. 1/5/7), the eager-dispatch
   vs compile-trace split at the real kernel call site;
 * serving integration satellites: BatchServer clock injection, the
-  ``_fresh_stats`` per-drain reset contract, the bounded ``events`` ring,
+  ``_fresh_stats`` per-drain reset contract, the bounded span ring,
   and the train-watchdog shim that must never re-grow its own bookkeeping.
 """
 import dataclasses
@@ -329,26 +329,26 @@ def test_batcher_clock_injection_and_fresh_stats_contract():
 
 
 def test_batcher_events_ring_is_bounded():
-    """The legacy ``events`` view is reconstructed from the span ring, so a
-    long-running server can no longer leak dispatch tuples without bound."""
+    """The batcher's dispatch spans live in the tracer's ring, so a
+    long-running server cannot leak them without bound."""
     cfg, model, params = _setup()
     srv = BatchServer(model, batch_slots=2, max_len=MAX_LEN, paged=True,
                       page_size=4, num_pages=24, prefill_chunk=4,
-                      trace_capacity=6)
+                      trace_capacity=16)
     for i, p in enumerate(_prompts(cfg)):
         srv.submit(Request(rid=i, prompt=p, max_new_tokens=MAX_NEW,
                            eos_id=-1))
     srv.run_until_drained(params)
-    assert len(srv.tracer.spans) <= 6 and srv.tracer.dropped > 0
-    ev = srv.events
-    assert ev, "events view empty"
+    assert len(srv.tracer.spans) <= 16 and srv.tracer.dropped > 0
+    ev = [s for s in srv.tracer.spans
+          if s.name in ("prefill_chunk", "decode")]
+    assert ev, "no dispatch span in the ring"
     for e in ev:
-        assert e[0] in ("prefill_chunk", "decode")
-        if e[0] == "prefill_chunk":
-            _, rid, start, end = e
-            assert isinstance(rid, int) and 0 <= start < end
+        if e.name == "prefill_chunk":
+            assert isinstance(e.attrs["rid_int"], int)
+            assert 0 <= e.attrs["start"] < e.attrs["end"]
         else:
-            assert isinstance(e[1], tuple)
+            assert all(isinstance(r, int) for r in e.attrs["rids"])
 
 
 def test_router_span_tree_for_retried_faulted_request():

@@ -241,12 +241,14 @@ def test_long_prefill_interleaves_with_decode():
     srv.submit(Request(rid=1, prompt=rng.integers(0, cfg.vocab, size=(40,)),
                        max_new_tokens=4))
     srv.run_until_drained(params)
-    ev = srv.events
+    ev = [s for s in srv.tracer.spans
+          if s.name in ("prefill_chunk", "decode")]
     chunks = [i for i, e in enumerate(ev)
-              if e[0] == "prefill_chunk" and e[1] == 1]
+              if e.name == "prefill_chunk" and e.attrs["rid_int"] == 1]
     assert len(chunks) == 5, "40-token prompt must split into 5 8-token chunks"
     for lo, hi in zip(chunks, chunks[1:]):
-        assert any(e[0] == "decode" and 0 in e[1] for e in ev[lo:hi]), \
+        assert any(e.name == "decode" and 0 in e.attrs["rids"]
+                   for e in ev[lo:hi]), \
             "active slot must keep decoding between the long prompt's chunks"
 
 

@@ -118,6 +118,7 @@ def drain(srv, params, prompts, rid0: int, *, prompt_kv: bool = False):
     decode step taken) and the third item holds, per cache leaf, the K/V
     rows the served prefill wrote for each slot's prompt; else it is None."""
     import jax
+    from repro.models.attention import from_blocks
     from repro.serve.batcher import Request
     t0 = time.perf_counter()
     for i, p in enumerate(prompts):
@@ -128,7 +129,8 @@ def drain(srv, params, prompts, rid0: int, *, prompt_kv: bool = False):
         n = [len(s.req.prompt) for s in srv.slots]
         check(sum(n) == sum(len(p) for p in prompts),
               "first step did not admit every prompt")
-        kv = [np.concatenate([np.asarray(leaf[:, i, :k], np.float32)
+        kv = [np.concatenate([np.asarray(from_blocks(leaf[:, i])[:, :k],
+                                         np.float32)
                               for i, k in enumerate(n)], axis=1)
               for leaf in map(jax.device_get, jax.tree.leaves(srv.cache))]
     done = srv.run_until_drained(params)

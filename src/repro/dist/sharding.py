@@ -209,9 +209,10 @@ def cache_specs(cache: PyTree, mesh, *, batch: int) -> PyTree:
     batch dim position is known structurally from the path (init_cache's
     layout), with a size-equality scan only as fallback for foreign trees;
     size-matching alone would mis-shard when a stack dim happens to equal
-    the batch size. KV caches additionally shard the kv-head dim
-    (second-to-last) over "model" when it divides, mirroring the attention
-    projections.
+    the batch size. KV caches additionally shard the kv-head dim over
+    "model" when it divides, mirroring the attention projections: third from
+    last in the blocked self-attention leaves (..., B, J, KV, hd, blk),
+    second from last in the encoder's cross K/V rows (..., B, T, KV, hd).
     """
     sizes = _axis_sizes(mesh)
 
@@ -230,7 +231,8 @@ def cache_specs(cache: PyTree, mesh, *, batch: int) -> PyTree:
             axes[bdim] = _batch_axes(mesh, batch)
         leaf_name = parts[-1]
         if leaf_name in ("k", "v") and ndim >= 4:
-            axes[ndim - 2] = "model" if "model" in sizes else None
+            kv_dim = ndim - (2 if parts[0] == "cross_kv" else 3)
+            axes[kv_dim] = "model" if "model" in sizes else None
         return _guarded(axes, shape, sizes)
 
     return jax.tree_util.tree_map_with_path(one, cache)

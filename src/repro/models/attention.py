@@ -9,7 +9,8 @@ local/global patterns (gemma3 5:1) without unrolling the stack.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import math
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,9 @@ from repro.models import layers as L
 
 Array = jax.Array
 NEG_INF = -2.0e38
+# Cache rows are kept in blocks of up to 128 (the TPU's lane width): see
+# row_block.
+_LANES = 128
 
 
 def gqa_init(key, cfg: ModelConfig, dtype) -> dict:
@@ -33,41 +37,102 @@ def gqa_init(key, cfg: ModelConfig, dtype) -> dict:
     }
 
 
-def _cache_write(buf: Array, new: Array, cache_pos,
-                 write_mask: Optional[Array] = None) -> Array:
-    """Write ``new`` (B, s, ...) rows into ``buf`` (B, S_max, ...) at
-    ``cache_pos``.
+def row_block(max_len: int) -> int:
+    """Rows per block of a contiguous cache leaf: 128, or the largest divisor
+    of ``max_len`` below it.
 
-    Scalar ``cache_pos``: shared offset (prefill / legacy decode) — a single
-    dynamic slice. ``(B,)`` vector: per-slot offsets (continuous-batching
-    decode) — one dynamic slice per batch row via vmap, lowering to a batched
-    scatter. Slot i's row lands at ``buf[i, cache_pos[i]]``.
+    A leaf keeps each layer's rows in blocks, (L, B, max_len // blk, *feat,
+    blk), a row's position within its block on the last axis. The TPU then
+    lays any feature width out unpadded (a 64-wide head would fill half of
+    each 128-lane row), a slot's block is contiguous, so writing one new row
+    rewrites one contiguous block in place, and the decode's attention
+    contracts the blocks as they lie, with batch, block and head leading."""
+    return math.gcd(max_len, _LANES)
+
+
+def to_blocks(rows: Array, blk: int) -> Array:
+    """(n, S, *feat) rows -> (n, S // blk, *feat, blk) blocks."""
+    x = rows.reshape((rows.shape[0], -1, blk) + rows.shape[2:])
+    return jnp.moveaxis(x, 2, -1)
+
+
+def from_blocks(blocks: Array) -> Array:
+    """(n, J, *feat, blk) blocks -> (n, J * blk, *feat) rows, in order."""
+    x = jnp.moveaxis(blocks, -1, 2)
+    return x.reshape((x.shape[0], -1) + x.shape[3:])
+
+
+class LayerSlot(NamedTuple):
+    """One layer of a cache leaf stacked on a leading layer axis: the whole
+    ``stack`` (L, ...) and the ``layer`` index. The layer scan carries the
+    stacks, so writes land in them in place and :meth:`read` indexes the
+    layer out where attention reads it; no layer-sized slice is rebuilt and
+    written back."""
+    stack: Array
+    layer: Array
+
+    def read(self) -> Array:
+        return jax.lax.dynamic_index_in_dim(self.stack, self.layer, 0,
+                                            keepdims=False)
+
+
+def _cache_write(dst: LayerSlot, new: Array, cache_pos,
+                 write_mask: Optional[Array] = None) -> LayerSlot:
+    """Write ``new`` (B, s, *feat) rows into layer ``dst.layer`` of
+    ``dst.stack``, a blocked leaf (L, B, J, *feat, blk) (see
+    :func:`row_block`), at ``cache_pos``; returns the slot with the stack
+    updated in place.
+
+    Scalar ``cache_pos``: shared offset (prefill / legacy decode) — one
+    dynamic_update_slice per leaf. ``(B,)`` vector: per-slot offsets
+    (continuous-batching decode) — one dynamic_update_slice per slot. Slot
+    i's rows land at positions ``cache_pos[i]:``, clamped into the cache as
+    dynamic_update_slice clamps. Each write covers the whole blocks that hold
+    the new rows: they are read, the new rows selected in, and written back.
 
     ``write_mask`` (optional, (B,) bool): rows with a False mask keep their
     existing cache content — the bucketed batched prefill runs a full-width
     forward straight over the SHARED slot cache and only commits the rows
-    being admitted, so live slots decoding next door are untouched. The
-    masked form still lowers to one dynamic_update_slice per leaf (the slice
-    is re-read, selected, and written back), never a per-leaf scatter.
+    being admitted, so live slots decoding next door are untouched. The mask
+    selects in the same read-select-write, never a per-leaf scatter.
     """
+    buf, layer = dst
     new = new.astype(buf.dtype)
+    b, s = new.shape[:2]
+    n_blk, blk = buf.shape[2], buf.shape[-1]
+    nb = min(n_blk, -(-(s + blk - 1) // blk))   # blocks s rows can touch
+    win = nb * blk
     pos = jnp.asarray(cache_pos, jnp.int32)
+    p = jnp.clip(pos, 0, n_blk * blk - s)
+    start = jnp.clip(p // blk, 0, n_blk - nb)   # first block written
+    shift = p - start * blk                     # first new row's place in it
+    feat = (1,) * (new.ndim - 2)
+    tail = (0,) * (new.ndim - 1)                # feature and in-block axes
+
+    def write(buf, at, rows, shift, keep):
+        n = rows.shape[0]
+        if s == 1:      # one row: broadcast across its block
+            src = rows.reshape((n, 1) + rows.shape[2:] + (1,))
+        else:           # the rows placed in the window, then blocked
+            src = to_blocks(jax.lax.dynamic_update_slice_in_dim(
+                jnp.zeros((n, win) + rows.shape[2:], rows.dtype), rows,
+                shift, axis=1), blk)
+        off = jnp.arange(win, dtype=jnp.int32) - shift
+        take = ((off >= 0) & (off < s)).reshape((1, nb) + feat + (blk,))
+        if keep is not None:
+            take = take & keep.reshape((-1, 1) + feat + (1,))
+        cur = jax.lax.dynamic_slice(
+            buf, at, (1, n, nb) + rows.shape[2:] + (blk,))[0]
+        return jax.lax.dynamic_update_slice(
+            buf, jnp.where(take, src, cur)[None], at)
+
     if pos.ndim == 0:
-        if write_mask is not None:
-            cur = jax.lax.dynamic_slice_in_dim(buf, pos, new.shape[1], axis=1)
-            keep = write_mask.reshape((-1,) + (1,) * (new.ndim - 1))
-            new = jnp.where(keep, new, cur)
-        return jax.lax.dynamic_update_slice_in_dim(buf, new, pos, axis=1)
-
-    def one(row, n, p, m=None):
-        if m is not None:
-            cur = jax.lax.dynamic_slice_in_dim(row, p, n.shape[0], axis=0)
-            n = jnp.where(m, n, cur)
-        return jax.lax.dynamic_update_slice_in_dim(row, n, p, axis=0)
-
-    if write_mask is not None:
-        return jax.vmap(one)(buf, new, pos, write_mask)
-    return jax.vmap(one)(buf, new, pos)
+        return dst._replace(stack=write(buf, (layer, 0, start) + tail, new,
+                                        shift, write_mask))
+    for i in range(b):
+        buf = write(buf, (layer, i, start[i]) + tail, new[i:i + 1], shift[i],
+                    None if write_mask is None else write_mask[i:i + 1])
+    return dst._replace(stack=buf)
 
 
 def _paged_write(pool: Array, new: Array, page_table: Array, cache_pos,
@@ -116,6 +181,17 @@ def _paged_view(pool: Array, page_table: Array) -> Array:
     rows = (page_table.astype(jnp.int32)[:, :, None] * ps
             + jnp.arange(ps, dtype=jnp.int32)[None, None, :])
     return flat[rows.reshape(b, max_pages * ps)]
+
+
+def _layer_pool(stack: Array, layer, page_table: Array
+                ) -> Tuple[Array, Array]:
+    """A pool stacked on the layer axis, (L, P, ps, ...), seen as one
+    (L*P, ps, ...) pool, with ``page_table`` shifted to ``layer``'s P pages:
+    the paged write, gather and kernel then address that layer inside the
+    carried stack (the reshape merges leading dims and moves nothing)."""
+    n_pages = stack.shape[1]
+    return (stack.reshape((-1,) + stack.shape[2:]),
+            page_table.astype(jnp.int32) + layer * n_pages)
 
 
 def _cache_end(cache_pos, s: int) -> Array:
@@ -239,21 +315,59 @@ def _sdpa(q: Array, k: Array, v: Array, keep: Optional[Array]) -> Array:
     return out.reshape(b, sq, h, hd)
 
 
+def _block_keep(keep: Array, n_blk: int, blk: int) -> Array:
+    """(B or 1, Sq, J*blk) keep-mask -> (B or 1, J, Sq, blk), laid out like
+    the scores over blocked cache rows."""
+    return jnp.moveaxis(keep.reshape(keep.shape[:2] + (n_blk, blk)), 2, 1)
+
+
+def _sum_blocks(partial: Array) -> Array:
+    """Sum per-block partial products over the block axis (1). The barrier
+    keeps the sum out of the contraction before it: folded into it, the
+    contraction would run over blocks and rows at once, and the TPU would
+    relayout the whole cache leaf to put its heads before its blocks."""
+    return jax.lax.optimization_barrier(partial).sum(axis=1)
+
+
+def _sdpa_blocks(q: Array, k: Array, v: Array, keep: Array) -> Array:
+    """:func:`_sdpa` over blocked cache rows (see :func:`row_block`): q
+    (B,Sq,H,hd), k/v (B,J,KV,hd,blk), keep (B or 1,Sq,J*blk) ->
+    (B,Sq,H,hd). Each block is contracted as it lies, with batch, block and
+    head as the batch axes; the softmax runs over all blocks' rows."""
+    b, sq, h, hd = q.shape
+    n_blk, kv, blk = k.shape[1], k.shape[2], k.shape[-1]
+    group = h // kv
+    q = jnp.broadcast_to(q.reshape(b, 1, sq, kv, group, hd),
+                         (b, n_blk, sq, kv, group, hd))
+    scores = jnp.einsum("bjqkgh,bjkhl->bjkgql", q, k,
+                        preferred_element_type=jnp.float32) / (hd ** 0.5)
+    keep = _block_keep(keep, n_blk, blk)[:, :, None, None]
+    probs = jax.nn.softmax(jnp.where(keep, scores, NEG_INF), axis=(1, 5))
+    out = jnp.einsum("bjkgql,bjkhl->bjqkgh", probs.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return _sum_blocks(out).astype(v.dtype).reshape(b, sq, h, hd)
+
+
 def gqa_apply(p: dict, x: Array, *, cfg: ModelConfig, positions: Array,
               window=0, rope_theta=None, causal: bool = True,
-              cache: Optional[dict] = None, cache_pos: Optional[Array] = None,
+              cache: Optional[dict] = None, layer=None,
+              cache_pos: Optional[Array] = None,
               cache_write_mask: Optional[Array] = None,
               prefill: bool = False, page_table: Optional[Array] = None,
               paged_impl: str = "gather") -> Tuple[Array, Optional[dict]]:
     """Full/prefill when cache is None; single-step decode when cache given.
 
-    cache = {"k": (B, S_max, KV, hd), "v": ...}; cache_pos: scalar int32 —
-    the number of tokens already in the cache (q is written at that offset).
+    cache = {"k": (L, B, J, KV, hd, blk), "v": ...}: the layer group's K/V,
+    stacked on the layer axis and blocked (see :func:`row_block`), of which
+    this is layer ``layer``; the new rows are written into the stacks in
+    place and the stacks returned.
+    cache_pos: scalar int32 — the number of tokens already in the cache (q
+    is written at that offset) — or (B,) per-slot offsets.
     cache_write_mask: optional (B,) bool — rows with False keep their cached
     K/V (bucketed prefill into a shared slot cache).
 
     When ``page_table`` (B, max_pages) is given the cache leaves are page
-    POOLS (P, ps, KV, hd) shared across sequences; k/v rows scatter through
+    POOLS (L, P, ps, KV, hd) shared across sequences; k/v rows scatter through
     the table and attention runs either over the gathered contiguous view
     (``paged_impl="gather"`` — bit-identical to the contiguous decode branch)
     or the in-kernel-gather Pallas path (``paged_impl="flash"``). The paged
@@ -281,51 +395,58 @@ def gqa_apply(p: dict, x: Array, *, cfg: ModelConfig, positions: Array,
             out = _sdpa(q, k, v, keep)
         new_cache = None
     elif page_table is not None:
-        k_pool = _paged_write(cache["k"], k, page_table, cache_pos,
-                              cache_write_mask)
-        v_pool = _paged_write(cache["v"], v, page_table, cache_pos,
-                              cache_write_mask)
+        k_pool, pt = _layer_pool(cache["k"], layer, page_table)
+        v_pool, _ = _layer_pool(cache["v"], layer, page_table)
+        k_pool = _paged_write(k_pool, k, pt, cache_pos, cache_write_mask)
+        v_pool = _paged_write(v_pool, v, pt, cache_pos, cache_write_mask)
         pos = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32).reshape(-1),
                                (b,))
         if paged_impl == "flash":
             from repro.core.gemm import current_config
             from repro.kernels.flash_attention import flash_attention_paged
             out = flash_attention_paged(
-                q.transpose(0, 2, 1, 3), k_pool, v_pool, page_table,
+                q.transpose(0, 2, 1, 3), k_pool, v_pool, pt,
                 pos + s, pos, window if window is not None else 0,
                 causal=causal, interpret=current_config().interpret)
             out = out.transpose(0, 2, 1, 3)
         else:
-            kg = _paged_view(k_pool, page_table)
-            vg = _paged_view(v_pool, page_table)
+            kg = _paged_view(k_pool, pt)
+            vg = _paged_view(v_pool, pt)
             s_max = kg.shape[1]
             k_pos = jnp.arange(s_max, dtype=jnp.int32)
             valid = k_pos[None, :] < _cache_end(pos, s)
             q_pos = positions if positions.ndim == 2 else positions[None, :]
             keep = _mask(q_pos, k_pos[None, :], window, causal) \
                 & valid[:, None, :]
-            out = _sdpa(q, kg, vg, keep)
-        new_cache = {"k": k_pool, "v": v_pool}
+            blk = row_block(s_max)
+            out = _sdpa_blocks(q, to_blocks(kg, blk), to_blocks(vg, blk), keep)
+        new_cache = {"k": k_pool.reshape(cache["k"].shape),
+                     "v": v_pool.reshape(cache["v"].shape)}
     elif prefill and cfg.attention_impl == "flash":
         # prefill into EMPTY cache rows: attention over the prompt == flash
         # self-attention; k/v written at offset 0 (32k cells never touch an
         # (S,S) score tensor this way — §Perf)
-        k_cache = _cache_write(cache["k"], k, cache_pos, cache_write_mask)
-        v_cache = _cache_write(cache["v"], v, cache_pos, cache_write_mask)
+        k_slot = _cache_write(LayerSlot(cache["k"], layer), k, cache_pos,
+                              cache_write_mask)
+        v_slot = _cache_write(LayerSlot(cache["v"], layer), v, cache_pos,
+                              cache_write_mask)
         out = _flash_sdpa(q, k, v, window, causal)
-        new_cache = {"k": k_cache, "v": v_cache}
+        new_cache = {"k": k_slot.stack, "v": v_slot.stack}
     else:
         # decode: write this step's k/v at cache_pos (per-slot rows when
-        # cache_pos is a (B,) vector), attend over the cache
-        k_cache = _cache_write(cache["k"], k, cache_pos, cache_write_mask)
-        v_cache = _cache_write(cache["v"], v, cache_pos, cache_write_mask)
-        s_max = k_cache.shape[1]
+        # cache_pos is a (B,) vector), attend over the layer's cache rows
+        k_slot = _cache_write(LayerSlot(cache["k"], layer), k, cache_pos,
+                              cache_write_mask)
+        v_slot = _cache_write(LayerSlot(cache["v"], layer), v, cache_pos,
+                              cache_write_mask)
+        k_cache, v_cache = k_slot.read(), v_slot.read()
+        s_max = k_cache.shape[1] * k_cache.shape[-1]
         k_pos = jnp.arange(s_max, dtype=jnp.int32)
         valid = k_pos[None, :] < _cache_end(cache_pos, s)
         q_pos = positions if positions.ndim == 2 else positions[None, :]
         keep = _mask(q_pos, k_pos[None, :], window, causal) & valid[:, None, :]
-        out = _sdpa(q, k_cache, v_cache, keep)
-        new_cache = {"k": k_cache, "v": v_cache}
+        out = _sdpa_blocks(q, k_cache, v_cache, keep)
+        new_cache = {"k": k_slot.stack, "v": v_slot.stack}
     return L.dense(out.reshape(b, s, cfg.n_heads * hd), p["wo"]), new_cache
 
 
@@ -355,16 +476,17 @@ def _mla_kv(p, c_kv: Array, cfg: ModelConfig) -> Tuple[Array, Array]:
 
 
 def mla_apply(p: dict, x: Array, *, cfg: ModelConfig, positions: Array,
-              window=0, cache: Optional[dict] = None,
+              window=0, cache: Optional[dict] = None, layer=None,
               cache_pos: Optional[Array] = None,
               cache_write_mask: Optional[Array] = None,
               prefill: bool = False, page_table: Optional[Array] = None,
               paged_impl: str = "gather") -> Tuple[Array, Optional[dict]]:
     """MLA: the KV cache stores only (c_kv, k_rope) — rank-512+64 per token.
 
-    cache = {"c_kv": (B, S_max, r), "k_rope": (B, S_max, rope_hd)};
-    cache_write_mask as in :func:`gqa_apply`. With ``page_table`` set the
-    leaves are pools (P, ps, r) / (P, ps, rope_hd) and the absorbed decode
+    cache = {"c_kv": (L, B, J, r, blk), "k_rope": (L, B, J, rope_hd, blk)},
+    stacked on the layer axis and blocked, written in place at layer
+    ``layer`` as in :func:`gqa_apply`, as is cache_write_mask. With ``page_table`` set the
+    leaves are pools (L, P, ps, r) / (L, P, ps, rope_hd) and the absorbed decode
     runs over the gathered view (or, for ``paged_impl="flash"``, the paged
     kernel with k = concat(c, rope), v = c and the pre-absorption scale —
     the flashinfer paged-MLA layout).
@@ -389,10 +511,11 @@ def mla_apply(p: dict, x: Array, *, cfg: ModelConfig, positions: Array,
         new_cache = None
         if cache is not None:   # prefill: write compressed cache, flash attn
             new_cache = {
-                "c_kv": _cache_write(cache["c_kv"], c_kv, cache_pos,
-                                     cache_write_mask),
-                "k_rope": _cache_write(cache["k_rope"], k_rope[:, :, 0, :],
-                                       cache_pos, cache_write_mask),
+                "c_kv": _cache_write(LayerSlot(cache["c_kv"], layer), c_kv,
+                                     cache_pos, cache_write_mask).stack,
+                "k_rope": _cache_write(LayerSlot(cache["k_rope"], layer),
+                                       k_rope[:, :, 0, :], cache_pos,
+                                       cache_write_mask).stack,
             }
         if cfg.attention_impl == "flash":
             # PERF (§Perf deepseek iter-1): flash for MLA — concat nope+rope
@@ -417,40 +540,57 @@ def mla_apply(p: dict, x: Array, *, cfg: ModelConfig, positions: Array,
         w_uv = w_ukv[..., m.nope_head_dim:]            # (r, H, v)
         q_eff = jnp.einsum("bqhn,rhn->bqhr", q_nope, w_uk)   # absorbed query
         if page_table is not None:
-            c_pool = _paged_write(cache["c_kv"], c_kv, page_table, cache_pos,
+            c_pool, pt = _layer_pool(cache["c_kv"], layer, page_table)
+            r_pool, _ = _layer_pool(cache["k_rope"], layer, page_table)
+            c_pool = _paged_write(c_pool, c_kv, pt, cache_pos,
                                   cache_write_mask)
-            r_pool = _paged_write(cache["k_rope"], k_rope[:, :, 0, :],
-                                  page_table, cache_pos, cache_write_mask)
-            new_cache = {"c_kv": c_pool, "k_rope": r_pool}
+            r_pool = _paged_write(r_pool, k_rope[:, :, 0, :], pt, cache_pos,
+                                  cache_write_mask)
+            new_cache = {"c_kv": c_pool.reshape(cache["c_kv"].shape),
+                         "k_rope": r_pool.reshape(cache["k_rope"].shape)}
             pos = jnp.broadcast_to(
                 jnp.asarray(cache_pos, jnp.int32).reshape(-1), (b,))
             if paged_impl == "flash":
                 from repro.core.gemm import current_config
                 from repro.kernels.flash_attention import flash_attention_paged
+                # the kernel reads k = concat(c, rope), built from this
+                # layer's pools alone
+                c_layer = LayerSlot(new_cache["c_kv"], layer).read()
+                r_layer = LayerSlot(new_cache["k_rope"], layer).read()
                 q_cat = jnp.concatenate([q_eff, q_rope], axis=-1)
-                k_cat = jnp.concatenate([c_pool, r_pool], -1)[:, :, None, :]
+                k_cat = jnp.concatenate([c_layer, r_layer],
+                                        -1)[:, :, None, :]
                 ctx = flash_attention_paged(
                     q_cat.transpose(0, 2, 1, 3), k_cat,
-                    c_pool[:, :, None, :], page_table, pos + s, pos, 0,
+                    c_layer[:, :, None, :], page_table, pos + s, pos, 0,
                     scale=1.0 / ((m.nope_head_dim + m.rope_head_dim) ** 0.5),
                     interpret=current_config().interpret)
                 ctx = ctx.transpose(0, 2, 1, 3)        # (B, s, H, r)
                 out = jnp.einsum("bqhr,rhv->bqhv", ctx, w_uv)
                 out = out.reshape(b, s, h * m.v_head_dim)
                 return L.dense(out, p["wo"]), new_cache
-            c_cache = _paged_view(c_pool, page_table)
-            r_cache = _paged_view(r_pool, page_table)
+            c_cache = _paged_view(c_pool, pt)
+            r_cache = _paged_view(r_pool, pt)
+            blk = row_block(c_cache.shape[1])
+            c_cache, r_cache = to_blocks(c_cache, blk), to_blocks(r_cache, blk)
             cache_pos = pos
         else:
-            c_cache = _cache_write(cache["c_kv"], c_kv, cache_pos,
-                                   cache_write_mask)
-            r_cache = _cache_write(cache["k_rope"], k_rope[:, :, 0, :],
-                                   cache_pos, cache_write_mask)
-            new_cache = {"c_kv": c_cache, "k_rope": r_cache}
-        s_max = c_cache.shape[1]
-        scores = (jnp.einsum("bqhr,bsr->bhqs", q_eff.astype(jnp.float32),
+            c_slot = _cache_write(LayerSlot(cache["c_kv"], layer), c_kv,
+                                  cache_pos, cache_write_mask)
+            r_slot = _cache_write(LayerSlot(cache["k_rope"], layer),
+                                  k_rope[:, :, 0, :], cache_pos,
+                                  cache_write_mask)
+            new_cache = {"c_kv": c_slot.stack, "k_rope": r_slot.stack}
+            c_cache, r_cache = c_slot.read(), r_slot.read()
+        # the blocked latent rows (B, J, r|rope, blk), contracted block by
+        # block as they lie (see _sdpa_blocks)
+        n_blk, blk = c_cache.shape[1], c_cache.shape[-1]
+        s_max = n_blk * blk
+        lead = lambda t: jnp.broadcast_to(
+            t[:, None], (b, n_blk) + t.shape[1:]).astype(jnp.float32)
+        scores = (jnp.einsum("bjqhr,bjrl->bjhql", lead(q_eff),
                              c_cache.astype(jnp.float32))
-                  + jnp.einsum("bqhd,bsd->bhqs", q_rope.astype(jnp.float32),
+                  + jnp.einsum("bjqhd,bjdl->bjhql", lead(q_rope),
                                r_cache.astype(jnp.float32)))
         scores = scores / ((m.nope_head_dim + m.rope_head_dim) ** 0.5)
         kv_positions = jnp.broadcast_to(
@@ -458,9 +598,11 @@ def mla_apply(p: dict, x: Array, *, cfg: ModelConfig, positions: Array,
         q_positions = positions if positions.ndim == 2 else positions[None, :]
         keep = _mask(q_positions, kv_positions, window, True) \
             & (kv_positions < _cache_end(cache_pos, s))[:, None, :]
-        scores = jnp.where(keep[:, None, :, :], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("bhqs,bsr->bqhr", probs.astype(c_cache.dtype), c_cache)
+        keep = _block_keep(keep, n_blk, blk)[:, :, None]
+        probs = jax.nn.softmax(jnp.where(keep, scores, NEG_INF), axis=(1, 4))
+        ctx = jnp.einsum("bjhql,bjrl->bjqhr", probs.astype(c_cache.dtype),
+                         c_cache, preferred_element_type=jnp.float32)
+        ctx = _sum_blocks(ctx).astype(c_cache.dtype)
         out = jnp.einsum("bqhr,rhv->bqhv", ctx, w_uv)   # absorbed values
         out = out.reshape(b, s, h * m.v_head_dim)
         return L.dense(out, p["wo"]), new_cache

@@ -68,25 +68,35 @@ def block_init(key, cfg: ModelConfig, dtype, *, kind: str) -> dict:
 
 def block_apply(p: dict, x: Array, *, cfg: ModelConfig, kind: str,
                 positions: Array, window=0, theta=None, causal: bool = True,
-                cache: Optional[dict] = None, cache_pos=None,
+                cache: Optional[dict] = None, layer=None, cache_pos=None,
                 cache_write_mask: Optional[Array] = None,
                 enc: Optional[Array] = None,
                 cross_kv: Optional[dict] = None, prefill: bool = False,
                 page_table: Optional[Array] = None,
                 paged_impl: str = "gather",
                 ) -> Tuple[Array, Optional[dict], Array]:
-    """Returns (x, new_cache, aux_loss)."""
+    """Returns (x, new_cache, aux_loss).
+
+    ``cache`` holds the layer group's caches stacked on the layer axis and
+    ``layer`` is this layer's index in them; the returned caches are the
+    same stacks with this layer's state updated."""
     aux = jnp.zeros((), jnp.float32)
     if kind in ("ssm1", "ssm2"):
         if page_table is not None:
             raise ValueError("paged KV cache requires attention layers; "
                              f"got layer kind {kind!r}")
+        # an SSM layer's state is a running summary, replaced whole each call
+        state = None if cache is None else jax.tree.map(
+            lambda t: A.LayerSlot(t, layer).read(), cache)
         if kind == "ssm1":
-            h, new_cache = S.mamba1_apply(p["ssm"], norm_apply(x, p["ln1"], cfg),
-                                          cfg=cfg, cache=cache, prefill=prefill)
+            h, new_state = S.mamba1_apply(p["ssm"], norm_apply(x, p["ln1"], cfg),
+                                          cfg=cfg, cache=state, prefill=prefill)
         else:
-            h, new_cache = S.mamba2_apply(p["ssm"], norm_apply(x, p["ln1"], cfg),
-                                          cfg=cfg, cache=cache)
+            h, new_state = S.mamba2_apply(p["ssm"], norm_apply(x, p["ln1"], cfg),
+                                          cfg=cfg, cache=state)
+        new_cache = None if cache is None else jax.tree.map(
+            lambda t, n: jax.lax.dynamic_update_index_in_dim(
+                t, n.astype(t.dtype), layer, 0), cache, new_state)
         return x + h, new_cache, aux
 
     attn_fn = (functools.partial(A.mla_apply, prefill=prefill)
@@ -95,7 +105,7 @@ def block_apply(p: dict, x: Array, *, cfg: ModelConfig, kind: str,
                    prefill=prefill))
     h, new_cache = attn_fn(p["attn"], norm_apply(x, p["ln1"], cfg), cfg=cfg,
                            positions=positions, window=window, cache=cache,
-                           cache_pos=cache_pos,
+                           layer=layer, cache_pos=cache_pos,
                            cache_write_mask=cache_write_mask,
                            page_table=page_table, paged_impl=paged_impl)
     x = x + h
@@ -230,7 +240,13 @@ def _scan_group(p_stacked, x, *, cfg, kind, positions, windows=None,
                 cache_write_mask=None, enc=None, cross_kvs=None,
                 prefill=False, page_table=None, paged_impl="gather"):
     """lax.scan over a stacked layer group. caches/cross_kvs are stacked on
-    the leading (layer) axis when present."""
+    the leading (layer) axis when present.
+
+    The caches ride in the scan's carry, not its xs/ys: each layer writes its
+    new rows (or, for an SSM layer, its new state) into the stacks at its
+    own index in place and reads its K/V from there, so a donated cache
+    aliases the program's output and no layer-sized slice is copied out or
+    stacked back. ``cross_kvs`` are only read, and stay in xs."""
     n = jax.tree_util.tree_leaves(p_stacked)[0].shape[0]
     if windows is None:
         windows = jnp.zeros((n,), jnp.int32)
@@ -238,33 +254,21 @@ def _scan_group(p_stacked, x, *, cfg, kind, positions, windows=None,
         thetas = jnp.full((n,), cfg.rope_theta, jnp.float32)
 
     def body(carry, xs):
-        x, aux_acc = carry
-        if caches is not None and cross_kvs is not None:
-            p, w, th, c, ckv = xs
-        elif caches is not None:
-            p, w, th, c = xs
-            ckv = None
-        elif cross_kvs is not None:
-            p, w, th, ckv = xs
-            c = None
-        else:
-            p, w, th = xs
-            c, ckv = None, None
-        x, new_c, aux = block_apply(
+        x, aux_acc, c = carry
+        p, w, th, i, ckv = xs
+        x, c, aux = block_apply(
             p, x, cfg=cfg, kind=kind, positions=positions, window=w, theta=th,
-            causal=causal, cache=c, cache_pos=cache_pos,
+            causal=causal, cache=c, layer=i, cache_pos=cache_pos,
             cache_write_mask=cache_write_mask, enc=enc,
             cross_kv=ckv, prefill=prefill, page_table=page_table,
             paged_impl=paged_impl)
-        return (x, aux_acc + aux), new_c
+        return (x, aux_acc + aux, c), None
 
     body = _maybe_remat(body, cfg)
-    xs = (p_stacked, windows, thetas)
-    if caches is not None:
-        xs = xs + (caches,)
-    if cross_kvs is not None:
-        xs = xs + (cross_kvs,)
-    (x, aux), new_caches = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+    xs = (p_stacked, windows, thetas, jnp.arange(n, dtype=jnp.int32),
+          cross_kvs)
+    (x, aux, new_caches), _ = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), caches), xs)
     return x, aux, new_caches
 
 
@@ -285,10 +289,14 @@ def _hybrid_forward(params, x, *, cfg, positions, caches=None, cache_pos=None,
         x, aux, new_c = _scan_group(p_grp, x, cfg=cfg, kind="ssm2",
                                     positions=positions, caches=c_grp,
                                     cache_pos=cache_pos)
+        # the shared layer's cache is scanned over, one layer at a time: a
+        # stack of one for gqa_apply
+        one = lambda tree: jax.tree.map(lambda t: t[None], tree)
         h, new_sa = A.gqa_apply(sa["attn"], norm_apply(x, sa["ln"], cfg),
                                 cfg=cfg, positions=positions, window=0,
-                                cache=sa_cache, cache_pos=cache_pos,
-                                prefill=prefill)
+                                cache=one(sa_cache), layer=0,
+                                cache_pos=cache_pos, prefill=prefill)
+        new_sa = jax.tree.map(lambda t: t[0], new_sa)
         x = x + h
         return (x, aux), (new_c, new_sa)
 
@@ -428,18 +436,25 @@ def sample_fn(params, hidden: Array, cfg: ModelConfig) -> Array:
 # ---------------------------------------------------------------------------
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None) -> dict:
-    """Zero caches, stacked per layer group (shapes match forward's scans)."""
+    """Zero caches, stacked per layer group (shapes match forward's scans).
+
+    Attention leaves hold their rows in blocks, (L, B, J, *feat, blk) with
+    J * blk = max_len (see attention.row_block)."""
     dtype = dtype or cfg.dtype
     caches: Dict[str, PyTree] = {}
+    blk = A.row_block(max_len)
+
+    def rows(n, *feat):
+        return jnp.zeros((n, batch, max_len // blk) + feat + (blk,), dtype)
 
     def kv(n):
-        return {"k": jnp.zeros((n, batch, max_len, cfg.n_kv_heads, cfg.hd), dtype),
-                "v": jnp.zeros((n, batch, max_len, cfg.n_kv_heads, cfg.hd), dtype)}
+        return {"k": rows(n, cfg.n_kv_heads, cfg.hd),
+                "v": rows(n, cfg.n_kv_heads, cfg.hd)}
 
     def mla_c(n):
         m = cfg.mla
-        return {"c_kv": jnp.zeros((n, batch, max_len, m.kv_lora_rank), dtype),
-                "k_rope": jnp.zeros((n, batch, max_len, m.rope_head_dim), dtype)}
+        return {"c_kv": rows(n, m.kv_lora_rank),
+                "k_rope": rows(n, m.rope_head_dim)}
 
     def ssm_c(n):
         s = cfg.ssm

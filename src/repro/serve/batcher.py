@@ -23,10 +23,16 @@ the critical path — applied to serving):
   round-trips per token drop by 1/chunk.
 * **Bucketed batched prefill**: prompts are padded to power-of-2 length
   buckets and same-bucket requests prefill together in ONE dispatch, written
-  straight into the shared slot cache via masked ``dynamic_update_slice``
-  (``Model.prefill_sample``) — no batch-1 scratch cache, no per-leaf
-  scatter, and the prefill jit cache is bounded to O(log max_len) entries
-  instead of one per distinct prompt length.
+  straight into the shared slot cache via one masked
+  ``dynamic_update_slice`` per leaf and layer (``Model.prefill_sample``) —
+  no batch-1 scratch cache, no per-leaf scatter, and the prefill jit cache
+  is bounded to O(log max_len) entries instead of one per distinct prompt
+  length.
+* **In-place cache writes**: the cache is donated to every program and the
+  layer scan carries the stacked leaves, so each layer writes only its new
+  rows into them where they lie (in decode, the aligned block of rows that
+  holds each slot's new row) and reads its K/V from there: no dispatch
+  copies a layer or a whole leaf of the cache.
 
 With ``quantized=True`` the dense/attention projections of the serving
 forward route through the paper's int8 FFIP path: weights are quantized

@@ -129,13 +129,19 @@ def test_data_and_cache_specs_shapes():
                          Mesh2x16x16)
     assert mid == P("data", None)
 
-    kv = {"k": jax.ShapeDtypeStruct((4, 32, 256, 16, 64), jnp.bfloat16)}
+    # K/V leaves hold their rows in blocks: (L, B, J, KV, hd, blk)
+    kv = {"k": jax.ShapeDtypeStruct((4, 32, 2, 16, 64, 128), jnp.bfloat16)}
     cs = shd.cache_specs(kv, Mesh16x16, batch=32)
-    assert cs["k"] == P(None, "data", None, "model", None)
+    assert cs["k"] == P(None, "data", None, "model", None, None)
     # kv-heads that do not divide the model axis stay replicated
-    kv8 = {"k": jax.ShapeDtypeStruct((4, 32, 256, 8, 64), jnp.bfloat16)}
+    kv8 = {"k": jax.ShapeDtypeStruct((4, 32, 2, 8, 64, 128), jnp.bfloat16)}
     assert shd.cache_specs(kv8, Mesh16x16, batch=32)["k"] \
-        == P(None, "data", None, None, None)
+        == P(None, "data", None, None, None, None)
+    # the encoder's cross K/V are rows, (L, B, T, KV, hd)
+    cross = {"cross_kv": {"k": jax.ShapeDtypeStruct((4, 32, 256, 16, 64),
+                                                    jnp.bfloat16)}}
+    assert shd.cache_specs(cross, Mesh16x16, batch=32)["cross_kv"]["k"] \
+        == P(None, "data", None, "model", None)
     # hybrid layout (n_groups, period, B, ...): batch dim found structurally
     # even when a stack dim (period) collides with the batch size
     hyb = {"hybrid_groups": {

@@ -1,10 +1,12 @@
-"""Compile-only checks of the main-path Pallas kernels for a TPU v5e chip.
+"""Compile-only checks of the main-path Pallas kernels and serving programs
+for a TPU v5e chip.
 
 Each test compiles one kernel at minicpm-2b widths (d_model 2304, d_ff 5760,
 head dim 64, a 256-row prefill tile) with the TPU compiler against a
 described, not attached, v5e chip, and asserts the compiled program holds the
 Mosaic kernel (``tpu_custom_call``) — interpret mode would lower to plain HLO
-instead; the int8 dense layer is XLA code and is only compiled. Nothing
+instead; the int8 dense layer is XLA code and is only compiled. The
+serving programs are compiled through the server at test widths. Nothing
 runs. The topology is described inside a fixture, so only the
 test worker that runs this file loads the TPU compiler.
 """
@@ -135,3 +137,64 @@ def test_tensor_parallel_prefill_keeps_layout(topo, quantized):
     assert "all-reduce" in text                       # row-parallel sums
     assert "all-gather" not in text
     assert "all-to-all" not in text
+
+
+# minicpm-2b's attention (36 heads of 64, one K/V head each) at a small
+# model width; MLA at its smoke widths
+SERVING_CASES = {
+    "gqa": ("minicpm-2b", dict(n_heads=36, n_kv_heads=36, head_dim=64)),
+    "mla": ("deepseek-v2-lite-16b", {}),
+}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("case", sorted(SERVING_CASES))
+def test_serving_programs_update_the_cache_in_place(one_chip, case, program):
+    """The server's decode and bucketed prefill, compiled for one v5e: the
+    donated cache aliases the output whole, no cache leaf or layer of one is
+    copied, and the program needs less scratch than one cache leaf. With
+    head dim 64 the chip lays a leaf's rows along its lanes; a program that
+    slices layers out of the stack and stacks them back, or writes single
+    rows there, copies or relayouts whole leaves instead."""
+    import dataclasses
+    import re
+
+    import numpy as np
+
+    from repro import configs
+    from repro.models.model import build_model
+    from repro.serve.batcher import BatchServer
+
+    arch, widths = SERVING_CASES[case]
+    cfg = dataclasses.replace(
+        configs.smoke_config(configs.get_config(arch)), d_model=256,
+        n_layers=4, param_dtype="bfloat16", **widths)
+    model = build_model(cfg)
+    b, max_len = 8, 2048
+    srv = BatchServer(model, batch_slots=b, max_len=max_len)
+    place = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: model.init_cache(b, max_len)))
+    vec = lambda dt: jax.ShapeDtypeStruct((b,), dt, sharding=one_chip)
+    if program == "decode":
+        lowered = srv._decode.lower(params, vec(jnp.int32), cache,
+                                    vec(jnp.int32), vec(jnp.bool_),
+                                    vec(jnp.int32), vec(jnp.int32))
+    else:
+        toks = jax.ShapeDtypeStruct((b, 64), jnp.int32, sharding=one_chip)
+        lowered = srv._prefill_bucket.lower(params, toks, cache,
+                                            vec(jnp.int32), vec(jnp.bool_))
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    leaves = jax.tree.leaves(cache)
+    nbytes = [int(np.prod(x.shape)) * x.dtype.itemsize for x in leaves]
+    assert mem.alias_size_in_bytes == sum(nbytes)
+    assert mem.temp_size_in_bytes < max(nbytes), (mem.temp_size_in_bytes,
+                                                  nbytes)
+    sized = {",".join(map(str, dims)) for x in leaves
+             for dims in (x.shape, (1,) + x.shape[1:], x.shape[1:])}
+    copies = [m.group(1) for m in re.finditer(
+        r"= bf16\[([\d,]+)\]\S* copy\(", compiled.as_text())]
+    assert not [c for c in copies if c in sized], copies

@@ -2,8 +2,9 @@
 
 Once the window has closed, a sample of the requests the server finished,
 drawn from the seed and always holding the longest one, is run through the
-plain reference (:mod:`harness.reference`) over each prompt and its served
-tokens. For every served token the gap by which its reference logit lies
+plain reference of the configuration's family (``token_gaps`` of its module
+under ``bench/harness/families``) over each prompt and its served tokens, on
+the weights as the cell holds them: on a mesh, sharded as served. For every served token the gap by which its reference logit lies
 below the reference's best is read; the numbers compared are the widest gap
 and the mean gap over the sample, each against the limit the configuration
 file states. Served tokens are greedy, so a sound program reads gaps of its
@@ -14,8 +15,6 @@ from __future__ import annotations
 from typing import List
 
 import numpy as np
-
-from harness import reference
 
 SAMPLE_TOKENS = 320     # served tokens the sample holds at least ...
 SAMPLE_MAX = 16         # ... unless it reaches this many requests first
@@ -38,11 +37,12 @@ def sample(records, seed: int) -> List:
     return picked
 
 
-def gaps(params, picked, model: dict, max_len: int, max_out: int,
+def gaps(family, params, picked, model: dict, max_len: int, max_out: int,
          bits: int) -> np.ndarray:
-    """Reference gaps of every served token of ``picked``, concatenated."""
+    """Reference gaps of every served token of ``picked``, concatenated, by
+    the ``family`` module's reference."""
     import jax.numpy as jnp
-    key = reference.model_key(model)
+    key = family.model_key(model)
     out = []
     for r in picked:
         seq = np.zeros((max_len,), np.int32)
@@ -50,10 +50,10 @@ def gaps(params, picked, model: dict, max_len: int, max_out: int,
         seq[:n] = np.concatenate([r.prompt, np.asarray(r.out[:-1], np.int32)])
         served = np.zeros((max_out,), np.int32)
         served[:len(r.out)] = r.out
-        g, _ = reference.token_gaps(params, jnp.asarray(seq),
-                                    jnp.asarray(r.n_prompt, jnp.int32),
-                                    jnp.asarray(served), model=key,
-                                    bits=bits, n_out=max_out)
+        g, _ = family.token_gaps(params, jnp.asarray(seq),
+                                 jnp.asarray(r.n_prompt, jnp.int32),
+                                 jnp.asarray(served), model=key,
+                                 bits=bits, n_out=max_out)
         out.append(np.asarray(g)[:len(r.out)])
     return np.concatenate(out) if out else np.zeros((0,))
 

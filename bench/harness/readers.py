@@ -82,7 +82,7 @@ def dispatches(rec, program: str) -> Iterator[Tuple[float, object]]:
 def decode_least_seconds(rec, st) -> float:
     """The least time a decode dispatch could take on the cell's chips:
     its operations at the tier's peak, or its weight and live K/V bytes at
-    the memory bandwidth, whichever is larger."""
+    the memory bandwidth, whichever is larger, of all the chips together."""
     n, pk, shapes = rec["chips"], rec["peaks"], rec["shapes"]
     ops = st.decode_flops / (n * pk.compute(rec["tier"]))
     nbytes = shapes.decode_bytes(rec["tier"], st.decode_ctx_rows,
@@ -91,9 +91,12 @@ def decode_least_seconds(rec, st) -> float:
 
 
 def decode_roofline(rec) -> Optional[float]:
+    """Least time over device time, summed over every chip's execution of
+    each dispatch: each chip's is held to the least time of all the chips
+    together, so four chips busy for t read as one chip busy for 4t."""
     least = dev_s = 0.0
     for secs, st in dispatches(rec, DECODE_PROGRAM):
-        least += decode_least_seconds(rec, st) * rec["chips"]
+        least += decode_least_seconds(rec, st)
         dev_s += secs
     return share(least, dev_s)
 

@@ -1,11 +1,12 @@
-"""The plain reference the benchmark compares served tokens against.
+"""The parts of the plain reference that every family shares.
 
-A decoder forward written from the published equations (Llama layer: RMSNorm,
-rotary embedding in the rotate-half form, grouped-query causal attention,
-SwiGLU MLP, RMSNorm, unembedding), in float32 with every matrix product at
-``Precision.HIGHEST``. It imports nothing of the program: it reads the weight
-tree the benchmark made, by name, and computes one sequence at a time, layer
-by layer, attention in blocks of queries, so that it fits beside the weights.
+Each family module under ``bench/harness/families`` writes its decoder's
+forward from the published equations with these parts: projections,
+RMSNorm, rotary embedding in the rotate-half form and causal attention in
+blocks of queries, in float32 with every matrix product at
+``Precision.HIGHEST``. The reference imports nothing of the program: it reads
+the weight tree the benchmark made, by name, and computes one sequence at a
+time, layer by layer, so that it fits beside the weights.
 
 ``bits`` quantizes every projection as an integer tier states it: weights
 per output channel and activations per token row, both asymmetric with the
@@ -15,8 +16,6 @@ unembedding stays float, as on the tiers. ``bits=8`` is the int8 tier's
 reference; ``bits=4`` is the control one precision below it.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -100,51 +99,3 @@ def attention(q, k, v):
 
     out = jax.lax.map(block, jnp.arange(nb))
     return out.reshape(nb * Q_BLOCK, h, hd)[:s]
-
-
-@functools.partial(jax.jit, static_argnames=("model", "bits", "n_out"))
-def token_gaps(params, tokens, n_prompt, served, *, model: tuple, bits: int,
-               n_out: int):
-    """Reference logits at each served position and, for each served token,
-    the gap by which its logit lies below the reference's best.
-
-    tokens: (S,) prompt then served tokens, padded; n_prompt: () int32;
-    served: (n_out,) the served tokens (padded with 0). Returns (gaps
-    (n_out,), best (n_out,) the reference's own argmax): gaps[j] is for the
-    token served at position n_prompt - 1 + j."""
-    heads, kv_heads, hd, theta, eps, tied = model
-    s = tokens.shape[0]
-    pos = jnp.arange(s)
-    x = params["embed"]["table"][tokens].astype(jnp.float32)
-
-    def layer(x, p):
-        a = p["attn"]
-        h = rmsnorm(x, p["ln1"]["scale"], eps)
-        q = proj(h, a["wq"]["w"], bits).reshape(s, heads, hd)
-        k = proj(h, a["wk"]["w"], bits).reshape(s, kv_heads, hd)
-        v = proj(h, a["wv"]["w"], bits).reshape(s, kv_heads, hd)
-        o = attention(rope(q, pos, theta), rope(k, pos, theta), v)
-        x = x + proj(o.reshape(s, heads * hd), a["wo"]["w"], bits)
-        f = p["ffn"]
-        h = rmsnorm(x, p["ln2"]["scale"], eps)
-        g = proj(h, f["gate"]["w"], bits)
-        u = proj(h, f["up"]["w"], bits)
-        return x + proj(jax.nn.silu(g) * u, f["down"]["w"], bits), None
-
-    x, _ = jax.lax.scan(layer, x, params["layers"])
-    rows = jax.lax.dynamic_slice_in_dim(
-        jnp.pad(x, ((0, n_out), (0, 0))), n_prompt - 1, n_out, 0)
-    h = rmsnorm(rows, params["final_norm"]["scale"], eps)
-    w = (params["embed"]["table"].T if tied else params["unembed"]["w"])
-    logits = jnp.matmul(h, w.astype(jnp.float32), precision=HI)
-    chosen = jnp.take_along_axis(logits, served[:, None], axis=1)[:, 0]
-    return jnp.max(logits, axis=1) - chosen, jnp.argmax(logits, axis=1)
-
-
-def model_key(m: dict) -> tuple:
-    """The static shape tuple :func:`token_gaps` takes, from a configuration
-    file's ``model`` section."""
-    d, h = m["hidden_size"], m["num_attention_heads"]
-    return (h, m["num_key_value_heads"], m.get("head_dim") or d // h,
-            float(m["rope_theta"]), float(m["rms_norm_eps"]),
-            bool(m["tie_word_embeddings"]))

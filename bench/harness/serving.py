@@ -3,7 +3,12 @@
 Set-up makes the weights on the device from the seed in one jitted call,
 builds the program's ``BatchServer`` for the configuration, and warms up
 exactly the prefill buckets the cell's traffic uses plus its decode program,
-through the same ``submit``/``step`` calls the window makes. The window is
+through the same ``submit``/``step`` calls the window makes. What the model's
+family decides (its ``ModelConfig``, its work counts, initializers of its
+own leaves) comes from the family module the configuration names. A cell on
+more than one chip serves on a ``("data", "model")`` mesh of ``(1, chips)``:
+the weights are made sharded by the program's rules, so that no chip holds
+the whole model, and the server runs tensor-parallel. The window is
 then driven open loop (requests submitted at their due times, which a stall
 does not move) or as an offline backlog (the queue never empties; every slot
 is filled before the window opens, so the window measures the steady state
@@ -22,8 +27,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from harness import traffic as traffic_mod
-from harness.counts import ModelShapes
-from harness.spec import Cell, model_config
+from harness.spec import Cell
 
 CLOCK = time.perf_counter
 MIN_BUCKET = 4          # the server's smallest prompt bucket
@@ -68,14 +72,24 @@ class CompileClock:
             self.seconds += secs
 
 
-def init_weights(model, seed: int):
-    """Random weights in the program's parameter layout, made by one jitted
-    call on the device in the dtype they are served in: projections
-    N(0, 1/fan_in), embeddings N(0, 0.02^2), norm scales 1 + N(0, 0.1^2)."""
+def init_weights(model, seed: int, initializers=None, mesh=None):
+    """Random weights in the program's parameter layout, made from the seed
+    by one jitted call (:func:`weights_program`) on the device."""
+    return weights_program(model, initializers, mesh)(seed_key(seed))
+
+
+def weights_program(model, initializers=None, mesh=None):
+    """The jitted call that makes the weights from a raw key, in the dtype
+    they are served in: projections N(0, 1/fan_in), embeddings N(0, 0.02^2),
+    norm scales 1 + N(0, 0.1^2), and any other leaf by its family's
+    ``initializers`` ({leaf name: fn(key, shape, dtype)}). With a ``mesh``
+    every leaf is made where the program's sharding rules place it; the
+    values do not depend on that."""
     import jax
     import jax.numpy as jnp
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    initializers = initializers or {}
 
     def make(key):
         out = []
@@ -91,12 +105,33 @@ def init_weights(model, seed: int):
                 x = 1.0 + 0.1 * jax.random.normal(k, s.shape, jnp.float32)
             elif name == "b":
                 x = jnp.zeros(s.shape, jnp.float32)
+            elif name in initializers:
+                x = initializers[name](k, s.shape, s.dtype)
             else:
                 raise ValueError(f"no initializer for parameter {path}")
             out.append(x.astype(s.dtype))
         return jax.tree_util.tree_unflatten(treedef, out)
 
-    return jax.jit(make)(seed_key(seed))
+    if mesh is None:
+        return jax.jit(make)
+    from repro.dist import sharding
+    return jax.jit(make, out_shardings=sharding.to_named(
+        sharding.param_specs(shapes, mesh), mesh))
+
+
+def make_mesh(devices, chips: int):
+    """The ``("data", "model")`` mesh of ``(1, chips)`` over the first
+    ``chips`` of ``devices``, or None for a cell on one chip."""
+    if chips == 1:
+        return None
+    import jax
+    from jax.sharding import Mesh
+    devices = list(devices if devices is not None else jax.devices())
+    if len(devices) < chips:
+        raise ValueError(f"the cell needs {chips} chips, {len(devices)} "
+                         f"given")
+    return Mesh(np.array(devices[:chips]).reshape(1, chips),
+                ("data", "model"))
 
 
 @dataclasses.dataclass
@@ -132,12 +167,14 @@ class Step:
 class Session:
     """One cell: the model, its weights for a seed, and a warm server."""
 
-    def __init__(self, cell: Cell):
+    def __init__(self, cell: Cell, devices=None):
         self.cell = cell
         self.serving = cell.config["serving"]
         self.tier = cell.tier
-        self.shapes = ModelShapes.from_model(cell.config["model"])
-        self.model_cfg = model_config(cell.config)
+        fam = cell.family
+        self.shapes = fam.ModelShapes.from_model(cell.config["model"])
+        self.model_cfg = fam.model_config(cell.config)
+        self.mesh = make_mesh(devices, cell.chips)
         from repro.models.model import build_model
         self.model = build_model(self.model_cfg)
         self.slots = int(self.serving["slots"])
@@ -152,14 +189,15 @@ class Session:
         import jax
         self.params = None
         gc.collect()
-        self.params = init_weights(self.model, seed)
+        self.params = init_weights(self.model, seed,
+                                   self.cell.family.INITIALIZERS, self.mesh)
         jax.block_until_ready(self.params)
 
     def build_server(self) -> None:
         from repro.serve.batcher import BatchServer
         self.server = BatchServer(
             self.model, batch_slots=self.slots, max_len=self.max_len,
-            quantized=self.tier == "int8", clock=CLOCK)
+            quantized=self.tier == "int8", mesh=self.mesh, clock=CLOCK)
 
     def buckets(self) -> List[int]:
         return buckets(self.cell.traffic, self.max_len)

@@ -16,8 +16,11 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-COLLECTIVE = re.compile(r"^%(all-reduce|all-gather|reduce-scatter|"
-                        r"collective-permute|all-to-all)")
+_COLLECTIVE_OPS = (r"(all-reduce|all-gather|reduce-scatter|"
+                   r"collective-permute|all-to-all)(-start|-done)?")
+# by the instruction's name, or by its opcode where the name is another
+COLLECTIVE = re.compile(r"^%" + _COLLECTIVE_OPS
+                        + r"|^%\S+ = .+? " + _COLLECTIVE_OPS + r"\(")
 WRAPPER = re.compile(r"^%(while|conditional|call)[.\s]")
 
 
@@ -144,9 +147,16 @@ def top_ops(dev: Device, lo: float, hi: float, n: int = 10) -> List[list]:
     return [[k, v] for k, v in sorted(tot.items(), key=lambda kv: -kv[1])[:n]]
 
 
+def is_collective(name: str) -> bool:
+    """Whether an op is a collective: a synchronous one, or either half of
+    an async ``<op>-start``/``<op>-done`` pair."""
+    return COLLECTIVE.match(name) is not None
+
+
 def collective_seconds(dev: Device, lo: float, hi: float) -> float:
+    """Seconds of [lo, hi] in which a collective op ran on the device."""
     return sum(b - a for a, b in union(
-        [o for o in dev.ops if COLLECTIVE.match(o.name)], lo, hi))
+        [o for o in dev.ops if is_collective(o.name)], lo, hi))
 
 
 def programs_by_step(tr: Trace, dev: Device, program: str

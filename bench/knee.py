@@ -32,7 +32,7 @@ def main(argv=None) -> int:
     if devs is None:
         return 1
     cache_everything()
-    sess = serving.Session(cell)
+    sess = serving.Session(cell, devs)
     sess.make_weights(args.seed)
     sess.build_server()
     sess.warm_up()

@@ -1,6 +1,7 @@
 """Model step: useful operations of the traced prefill dispatches (real
 prompt tokens only) over the device time of the prefill program times the
-peak for the tier's arithmetic."""
+cell's chips' peak for the tier's arithmetic. Each chip's execution of a
+dispatch is credited the dispatch's operations over all the chips."""
 from __future__ import annotations
 
 from harness.readers import PREFILL_PROGRAM, dispatches, share
@@ -11,4 +12,5 @@ def read(rec):
     for s, pf in dispatches(rec, PREFILL_PROGRAM):
         ops += pf["flops"]
         secs += s
-    return share(ops, secs * rec["peaks"].compute(rec["tier"]))
+    return share(ops, secs * rec["chips"]
+                 * rec["peaks"].compute(rec["tier"]))

@@ -89,18 +89,24 @@ def measure(cell, seed: int, seconds: float, trace: bool, devs,
     from harness import check, peaks, readers, serving
     from harness import trace as tr_mod
 
-    sess = serving.Session(cell)
+    sess = serving.Session(cell, devs)
+    phases = [("weights", lambda: sess.make_weights(seed)),
+              ("server", sess.build_server)]
+    if sess.mesh is not None:
+        # the server first: on a mesh it makes its cache on one chip before
+        # sharding it, which would otherwise sit on top of that chip's weights
+        phases.reverse()
     t = [time.perf_counter()]
-    for phase in (lambda: sess.make_weights(seed), sess.build_server,
-                  sess.warm_up):
+    for _, phase in phases + [("warm-up", sess.warm_up)]:
         phase()
         t.append(time.perf_counter())
     trace_dir = pathlib.Path(tempfile.mkdtemp(prefix="bench-trace-")) \
         if trace else None
     run = sess.run(seed, seconds, trace_dir)
     setup_s = t[-1] - t_start + run["fill_s"]
-    note(f"setup {setup_s:.3f}s: start {t[0] - t_start:.3f}s, weights "
-         f"{t[1] - t[0]:.3f}s, server {t[2] - t[1]:.3f}s, warm-up "
+    note(f"setup {setup_s:.3f}s: start {t[0] - t_start:.3f}s, "
+         f"{phases[0][0]} {t[1] - t[0]:.3f}s, {phases[1][0]} "
+         f"{t[2] - t[1]:.3f}s, warm-up "
          f"{t[3] - t[2]:.3f}s, fill {run['fill_s']:.3f}s; "
          f"{sess.compiles.count} compiles or cache fetches, "
          f"{sess.compiles.seconds:.3f}s; buckets {sess.buckets()}")
@@ -120,7 +126,8 @@ def measure(cell, seed: int, seconds: float, trace: bool, devs,
          f"peak {memory_peak} bytes")
     t_ref = time.perf_counter()
     picked = check.sample(records, seed)
-    g = check.gaps(sess.params, picked, cell.config["model"], sess.max_len,
+    g = check.gaps(cell.family, sess.params, picked, cell.config["model"],
+                   sess.max_len,
                    int(max(serving.traffic_mod.output_lengths(cell.traffic))),
                    REFERENCE_BITS[cell.tier])
     cks = check.checks(
@@ -138,6 +145,11 @@ def measure(cell, seed: int, seconds: float, trace: bool, devs,
         note(f"ttft over {len(ttft)} requests: p50 "
              f"{readers.percentile(ttft, 50):.1f} ms, p90 "
              f"{readers.percentile(ttft, 90):.1f} ms")
+    tpot = readers.tpots_ms({"records": records, **run})
+    if tpot:
+        note(f"tpot over {len(tpot)} requests: p50 "
+             f"{readers.percentile(tpot, 50):.2f} ms, p90 "
+             f"{readers.percentile(tpot, 90):.2f} ms, max {max(tpot):.2f} ms")
 
     tr = None
     if trace_dir is not None:

@@ -77,7 +77,7 @@ def main(argv=None) -> int:
     if devs is None:
         return 1
     cache_everything()
-    sess = spans.SpanSession(cell)
+    sess = spans.SpanSession(cell, devs)
     sess.make_weights(args.seed)
     sess.build_server()
     sess.warm_up()

@@ -22,6 +22,7 @@ Runs on a TPU only.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import functools
 import json
 import pathlib
@@ -34,7 +35,7 @@ sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(BENCH.parent / "src"))
 
 from run import REFERENCE_BITS, cache_everything, find_chips  # noqa: E402
-from harness import check, reference, serving, spec, traffic  # noqa: E402
+from harness import check, serving, spec, traffic  # noqa: E402
 
 
 @functools.lru_cache(maxsize=None)
@@ -54,6 +55,13 @@ def _int8_forward(cfg):
     return fwd
 
 
+def mesh_scope(mesh):
+    """On a mesh, the ambient mesh the server dispatches under, so that the
+    program's forward wraps its Pallas kernels for the sharded weights."""
+    from repro.dist.context import mesh_context
+    return contextlib.nullcontext() if mesh is None else mesh_context(mesh)
+
+
 def _teacher(r, max_len: int) -> np.ndarray:
     """Prompt then served tokens but the last, padded to ``max_len``."""
     seq = np.zeros((max_len,), np.int32)
@@ -69,15 +77,17 @@ def control_tokens(sess, r, max_out: int, prepared=None) -> np.ndarray:
     import jax.numpy as jnp
     seq = _teacher(r, sess.max_len)
     if sess.tier == "float":
-        best = np.asarray(_int8_forward(sess.model_cfg)(prepared,
-                                                        jnp.asarray(seq)))
+        with mesh_scope(sess.mesh):
+            best = np.asarray(_int8_forward(sess.model_cfg)(
+                prepared, jnp.asarray(seq)))
         return best[r.n_prompt - 1:r.n_prompt - 1 + len(r.out)]
     served = np.zeros((max_out,), np.int32)
     served[:len(r.out)] = r.out
-    _, best = reference.token_gaps(
+    fam = sess.cell.family
+    _, best = fam.token_gaps(
         sess.params, jnp.asarray(seq), jnp.asarray(r.n_prompt, jnp.int32),
-        jnp.asarray(served), model=reference.model_key(
-            sess.cell.config["model"]), bits=4, n_out=max_out)
+        jnp.asarray(served), model=fam.model_key(sess.cell.config["model"]),
+        bits=4, n_out=max_out)
     return np.asarray(best)[:len(r.out)]
 
 
@@ -95,8 +105,9 @@ def control_gaps(sess, picked, max_out: int) -> np.ndarray:
                           out=[int(t) for t in control_tokens(
                               sess, r, max_out, prepared)])
            for r in picked]
-    return check.gaps(sess.params, ctl, sess.cell.config["model"],
-                      sess.max_len, max_out, REFERENCE_BITS[sess.tier])
+    return check.gaps(sess.cell.family, sess.params, ctl,
+                      sess.cell.config["model"], sess.max_len, max_out,
+                      REFERENCE_BITS[sess.tier])
 
 
 def readings(sess, seed: int, seconds: float, with_control: bool) -> dict:
@@ -108,8 +119,9 @@ def readings(sess, seed: int, seconds: float, with_control: bool) -> dict:
     sess.free_server()
     picked = check.sample(run["records"], seed)
     max_out = int(max(traffic.output_lengths(sess.cell.traffic)))
-    g = check.gaps(sess.params, picked, sess.cell.config["model"],
-                   sess.max_len, max_out, REFERENCE_BITS[sess.tier])
+    g = check.gaps(sess.cell.family, sess.params, picked,
+                   sess.cell.config["model"], sess.max_len, max_out,
+                   REFERENCE_BITS[sess.tier])
     out = {"seed": seed, "requests": len(picked), "tokens": int(g.size),
            "max_gap": float(g.max()), "mean_gap": float(g.mean()),
            "failed": sum(1 for r in run["records"] if r.error)}
@@ -132,7 +144,7 @@ def main(argv=None) -> int:
     if devs is None:
         return 1
     cache_everything()
-    sess = serving.Session(cell)
+    sess = serving.Session(cell, devs)
     for i, seed in enumerate(int(s) for s in args.seeds.split(",")):
         print(json.dumps(readings(sess, seed, args.seconds,
                                   i < args.control_seeds)), flush=True)

@@ -158,8 +158,9 @@ def test_control_is_not_correct(cell_factory, config, mix):
     sess.free_server()
     picked = check.sample(run["records"], SEED)
     max_out = int(max(traffic.output_lengths(cell.traffic)))
-    sound = check.gaps(sess.params, picked, cell.config["model"],
-                       sess.max_len, max_out, REFERENCE_BITS[cell.tier])
+    sound = check.gaps(cell.family, sess.params, picked,
+                       cell.config["model"], sess.max_len, max_out,
+                       REFERENCE_BITS[cell.tier])
     ctl = control_gaps(sess, picked, max_out)
     limits = cell.config["limits"]
     assert sound.max() <= limits["max_gap"] \

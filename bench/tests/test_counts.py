@@ -10,7 +10,9 @@ import pytest
 
 from conftest import BENCH
 from harness import spec
-from harness.counts import ModelShapes
+
+LLAMA = spec.family("llama")
+ModelShapes = LLAMA.ModelShapes
 
 
 def config(name):
@@ -19,13 +21,14 @@ def config(name):
 
 @pytest.mark.parametrize("name,params", [
     ("minicpm-2b.float", 2_724_880_896),
-    ("deepseek-coder-33b-l8.float", 4_705_082_368)])
+    ("deepseek-coder-33b-l8.float", 4_705_082_368),
+    ("deepseek-coder-33b-l31.tp4.float", 16_902_710_272)])
 def test_param_count(name, params):
     cfg = config(name)
     shapes = ModelShapes.from_model(cfg["model"])
     assert shapes.param_count() == params
     from repro.models.model import build_model
-    tree = jax.eval_shape(build_model(spec.model_config(cfg)).init,
+    tree = jax.eval_shape(build_model(LLAMA.model_config(cfg)).init,
                           jax.random.PRNGKey(0))
     assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(tree)) == params
 
